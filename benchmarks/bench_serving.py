@@ -6,11 +6,12 @@ reports request latency (p50/p99), time-to-first-token, and decode
 throughput — plus the warm-restart row: a restarted engine + scheduler
 over an already-populated plan cache must stage ZERO new plans.
 
-The measurement runs in a subprocess with ``JAX_PLATFORMS=cpu`` pinned
-(leaving the platform unset makes jax probe for accelerator plugins,
-which idles for minutes on images with the TPU toolchain) and a throwaway
-``REPRO_CACHE_DIR`` so the warm-restart measurement starts from a
-genuinely cold plan cache.
+The measurement runs in a CPU-only subprocess (``JAX_PLATFORMS=cpu``):
+its numbers are XLA:CPU counts and times, not chip numbers.  Only one
+process may hold a TPU, and the parent has already imported JAX, so this
+suite does not run on a chip; ``chip_smoke.py`` is the on-chip path.  A
+throwaway ``REPRO_CACHE_DIR`` makes the warm-restart measurement start
+from a genuinely cold plan cache.
 
 Standalone CI entry point::
 

@@ -49,6 +49,7 @@ docs/tuning.md for the calibration contract.
 from __future__ import annotations
 
 import dataclasses
+import logging
 import time
 from typing import Optional
 
@@ -68,6 +69,7 @@ __all__ = [
     "tune_num_workers",
     "autotune_stats",
     "reset_autotune_stats",
+    "record_candidate_failure",
     "StructureRateTracker",
     "structure_tracker",
     "observe_structure",
@@ -91,7 +93,19 @@ _STATS = {
     "benchmarks": 0,
     "plans_predicted": 0,
     "predict_fallbacks": 0,
+    "candidate_failures": 0,
 }
+
+_LOG = logging.getLogger(__name__)
+
+
+def record_candidate_failure(label: str, err: Exception) -> None:
+    """A candidate that raises while staging or running drops out of the
+    search, but never silently: it is logged with its error and counted in
+    ``autotune_stats()["candidate_failures"]``."""
+    _STATS["candidate_failures"] += 1
+    _LOG.warning("tuning candidate %s failed: %s: %s", label,
+                 type(err).__name__, err)
 
 
 def autotune_stats() -> dict:
@@ -504,7 +518,8 @@ def autotune(
             else:
                 kern = staginglib._cached(kind, vbr, opts, hints, n_cols=n_cols)
             t = measure(kern, val, x, warmup=warmup, iters=iters)
-        except Exception:  # a candidate that fails to stage just drops out
+        except Exception as e:
+            record_candidate_failure(label, e)
             continue
         timings[label] = t
         if t < best_t:

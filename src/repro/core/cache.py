@@ -8,7 +8,8 @@ in ``staging.py`` only lives for one process; this module is the on-disk
 half, so a *second* process (or a restarted server) skips the autotune
 search and goes straight to staging with the known-best plan.
 
-Layout (under ``$REPRO_CACHE_DIR`` or ``~/.cache/repro-sable``)::
+Layout (under ``$REPRO_CACHE_DIR``, default ``.repro_cache/`` at the root
+of the checkout)::
 
     plans/<key>.json        winning StagingOptions + measured timings
     structures/<hash>.npz   the VBR indirection arrays (never ``val``)
@@ -201,16 +202,19 @@ def plan_key(
     return "-".join(parts)
 
 
+# the checkout's root (src/repro/core/cache.py -> three levels up): the
+# default cache stays inside the checkout, never under the user's home
+DEFAULT_ROOT = os.path.normpath(os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), "..", "..", "..", ".repro_cache"
+))
+
+
 class PlanCache:
     """On-disk plan + structure store.  Safe for concurrent writers: files
     are written to a temp name and atomically renamed into place."""
 
     def __init__(self, root: Optional[str] = None):
-        self.root = str(
-            root
-            or os.environ.get("REPRO_CACHE_DIR")
-            or os.path.join(os.path.expanduser("~"), ".cache", "repro-sable")
-        )
+        self.root = str(root or os.environ.get("REPRO_CACHE_DIR") or DEFAULT_ROOT)
 
     # ------------------------------------------------------------------ #
     def _plan_path(self, key: str) -> str:
@@ -374,9 +378,7 @@ def default_cache() -> PlanCache:
     (including it being unset again)."""
     global _DEFAULT
     if not _DEFAULT_EXPLICIT:
-        resolved = os.environ.get("REPRO_CACHE_DIR") or os.path.join(
-            os.path.expanduser("~"), ".cache", "repro-sable"
-        )
+        resolved = os.environ.get("REPRO_CACHE_DIR") or DEFAULT_ROOT
         if _DEFAULT is None or _DEFAULT.root != resolved:
             _DEFAULT = PlanCache()
     return _DEFAULT

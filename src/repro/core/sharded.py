@@ -268,7 +268,6 @@ class ShardedStagedKernel:
         return fn
 
     def _build_spmd(self):
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         mesh, axis = self.mesh, self.axis
@@ -327,9 +326,9 @@ class ShardedStagedKernel:
             out_specs = P(None, *x_parts[1:])  # assembled in-ring
         else:
             out_specs = P(axis, *x_parts)
-        mapped = shard_map(
+        mapped = jax.shard_map(
             local, mesh=mesh, in_specs=(P(axis, None), P(*x_parts)),
-            out_specs=out_specs, check_rep=False,
+            out_specs=out_specs, check_vma=False,
         )
 
         def fn(val, x):

@@ -35,26 +35,21 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-
-try:  # TPU-specific pieces degrade gracefully on CPU (interpret mode)
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 from ..sparse.block_csr import BlockMatrix
-from .ops import bsr_spmm
+from .ops import _auto_interpret, bsr_spmm
 
 __all__ = ["dsd", "dds", "sdd"]
 
 
 def _resolve(backend: str, interpret):
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = _auto_interpret(interpret)
     if backend == "auto":
         backend = "pallas" if jax.default_backend() == "tpu" else "grouped"
     if backend not in ("grouped", "pallas"):
         raise ValueError(f"unknown bsr_ops backend {backend!r}")
-    return backend, bool(interpret)
+    return backend, interpret
 
 
 def _f0(a):
@@ -94,8 +89,6 @@ def _pick_bk(K: int) -> int:
 def _sdd_pallas(a, b, rows, cols, *, bm, bn, interpret):
     """(nnz, bm, bn) blocks of a @ b at (rows, cols); coordinates must be
     pre-clamped in-bounds (invalid slots are zeroed by the caller)."""
-    if pltpu is None:  # pragma: no cover - non-TPU builds without pltpu
-        raise RuntimeError("pallas TPU backend unavailable")
     nb = rows.shape[0]
     K = a.shape[1]
     bk = _pick_bk(K)
@@ -109,14 +102,11 @@ def _sdd_pallas(a, b, rows, cols, *, bm, bn, interpret):
         ],
         out_specs=pl.BlockSpec((1, bm, bn), lambda i, k, rows, cols: (i, 0, 0)),
     )
-    _CompilerParams = getattr(
-        pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-    )
     return pl.pallas_call(
         functools.partial(_sdd_kernel, acc_dtype=jnp.float32),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((nb, bm, bn), a.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")
         ),
         interpret=interpret,

@@ -21,11 +21,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU-specific pieces degrade gracefully on CPU (interpret mode)
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(row_ids, col_ids, tiles_ref, x_ref, y_ref, *, acc_dtype):
@@ -72,27 +68,18 @@ def bsr_spmm_pallas(
     out_spec = pl.BlockSpec((tm, bn), lambda j, b, rows, cols: (rows[b], j))
     out_shape = jax.ShapeDtypeStruct((m_pad, n), x.dtype)
 
-    if pltpu is not None:
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=grid,
-            in_specs=in_specs,
-            out_specs=out_spec,
-        )
-        # jax renamed TPUCompilerParams -> CompilerParams across releases
-        _CompilerParams = getattr(
-            pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-        )
-        compiler_params = _CompilerParams(
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=grid,
+        in_specs=in_specs,
+        out_specs=out_spec,
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
-        )
-        return pl.pallas_call(
-            kernel,
-            grid_spec=grid_spec,
-            out_shape=out_shape,
-            compiler_params=compiler_params,
-            interpret=interpret,
-        )(row_ids, col_ids, tiles, x)
-
-    # pragma: no cover - non-TPU builds without pltpu
-    raise RuntimeError("pallas TPU backend unavailable")
+        ),
+        interpret=interpret,
+    )(row_ids, col_ids, tiles, x)

@@ -1,10 +1,12 @@
 """Pallas TPU kernel: block-sparse SpMV over uniformized VBR tiles.
 
 SpMV is VPU-bound (no MXU): each grid step multiplies one (tm, tk) tile by
-a tk-slice of x and accumulates a tm-slice of y.  x and y are viewed as
-(k_pad/tk, tk) and (m_pad/tm, tm) so all Pallas blocks are 2-D and
-lane-aligned (tm, tk multiples of 128 on real hardware; anything in
-interpret mode).  Same sorted-rows accumulate-in-VMEM schedule as SpMM.
+a tk-slice of x and accumulates a tm-slice of y.  Mosaic wants the last two
+dims of every block divisible by (8, 128) or equal to the array's, so x is
+viewed as a (1, k_pad) row read in (1, tk) blocks and y as an (m_pad, 1)
+column written in (tm, 1) blocks: the lane reduction of a (tm, tk) tile
+lands as a (tm, 1) column, which is the y block's own layout.  Same
+sorted-rows accumulate-in-VMEM schedule as SpMM.
 """
 from __future__ import annotations
 
@@ -13,11 +15,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(row_ids, col_ids, tiles_ref, x_ref, y_ref, *, acc_dtype):
@@ -31,9 +29,9 @@ def _kernel(row_ids, col_ids, tiles_ref, x_ref, y_ref, *, acc_dtype):
         y_ref[...] = jnp.zeros_like(y_ref)
 
     tile = tiles_ref[0].astype(acc_dtype)  # (tm, tk)
-    xv = x_ref[0].astype(acc_dtype)  # (tk,)
-    acc = jnp.sum(tile * xv[None, :], axis=1)  # VPU reduce over lanes
-    y_ref[0, :] += acc.astype(y_ref.dtype)
+    xv = x_ref[...].astype(acc_dtype)  # (1, tk)
+    acc = jnp.sum(tile * xv, axis=1, keepdims=True)  # (tm, 1), VPU lane reduce
+    y_ref[...] += acc.astype(y_ref.dtype)
 
 
 def bsr_spmv_pallas(
@@ -49,37 +47,20 @@ def bsr_spmv_pallas(
     nb, tm, tk = tiles.shape
     (k_pad,) = x.shape
     assert k_pad % tk == 0 and m_pad % tm == 0
-    x2 = x.reshape(k_pad // tk, tk)
-
-    kernel = functools.partial(_kernel, acc_dtype=acc_dtype)
-    in_specs = [
-        pl.BlockSpec((1, tm, tk), lambda b, rows, cols: (b, 0, 0)),
-        pl.BlockSpec((1, tk), lambda b, rows, cols: (cols[b], 0)),
-    ]
-    out_spec = pl.BlockSpec((1, tm), lambda b, rows, cols: (rows[b], 0))
-    out_shape = jax.ShapeDtypeStruct((m_pad // tm, tm), x.dtype)
-
-    if pltpu is not None:
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(nb,),
-            in_specs=in_specs,
-            out_specs=out_spec,
-        )
-        # jax renamed TPUCompilerParams -> CompilerParams across releases
-        _CompilerParams = getattr(
-            pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-        )
-        compiler_params = _CompilerParams(
-            dimension_semantics=("arbitrary",),
-        )
-        y2 = pl.pallas_call(
-            kernel,
-            grid_spec=grid_spec,
-            out_shape=out_shape,
-            compiler_params=compiler_params,
-            interpret=interpret,
-        )(row_ids, col_ids, tiles, x2)
-        return y2.reshape(m_pad)
-
-    raise RuntimeError("pallas TPU backend unavailable")  # pragma: no cover
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(nb,),
+        in_specs=[
+            pl.BlockSpec((1, tm, tk), lambda b, rows, cols: (b, 0, 0)),
+            pl.BlockSpec((1, tk), lambda b, rows, cols: (0, cols[b])),
+        ],
+        out_specs=pl.BlockSpec((tm, 1), lambda b, rows, cols: (rows[b], 0)),
+    )
+    y = pl.pallas_call(
+        functools.partial(_kernel, acc_dtype=acc_dtype),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((m_pad, 1), x.dtype),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(row_ids, col_ids, tiles, x.reshape(1, k_pad))
+    return y.reshape(m_pad)

@@ -2,7 +2,8 @@
 
 ``interpret=None`` auto-selects: compiled Mosaic on TPU, interpret mode
 (Python-evaluated kernel body) elsewhere, so the same call sites work in
-CPU tests and on real hardware.
+CPU tests and on real hardware.  Interpret mode is a CPU-only testing aid:
+it is never chosen on a TPU, and asking for it there is an error.
 """
 from __future__ import annotations
 
@@ -18,9 +19,12 @@ __all__ = ["bsr_spmm", "bsr_spmv"]
 
 
 def _auto_interpret(interpret):
+    on_tpu = jax.default_backend() == "tpu"
     if interpret is None:
-        return jax.default_backend() != "tpu"
-    return interpret
+        return not on_tpu
+    if interpret and on_tpu:
+        raise ValueError("Pallas interpret mode is CPU-only; run compiled on a TPU")
+    return bool(interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("m_pad", "bn", "interpret"))

@@ -14,7 +14,13 @@ from __future__ import annotations
 import jax
 import numpy as np
 
-__all__ = ["make_production_mesh", "make_local_mesh", "make_staging_mesh", "HW"]
+__all__ = [
+    "make_mesh",
+    "make_production_mesh",
+    "make_local_mesh",
+    "make_staging_mesh",
+    "HW",
+]
 
 
 # TPU v5e hardware constants used by the roofline analysis
@@ -27,10 +33,25 @@ HW = {
 }
 
 
+def make_mesh(shape, axes, devices=None):
+    """The one mesh factory: every axis is ``AxisType.Auto``, which is what
+    ``with_sharding_constraint`` and the activation-sharding context
+    (``distributed/ctx.py``) expect.  ``jax.make_mesh`` alone now defaults
+    to Explicit axes.  ``devices`` pins the mesh to those devices in order
+    (default: all devices, laid out by ``jax.make_mesh``)."""
+    from jax.sharding import AxisType, Mesh
+
+    auto = (AxisType.Auto,) * len(axes)
+    if devices is None:
+        return jax.make_mesh(tuple(shape), tuple(axes), axis_types=auto)
+    grid = np.asarray(devices).reshape(tuple(shape))
+    return Mesh(grid, tuple(axes), axis_types=auto)
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_staging_mesh(
@@ -50,8 +71,6 @@ def make_staging_mesh(
     multiple host devices first:
     ``XLA_FLAGS=--xla_force_host_platform_device_count=8``.
     """
-    from jax.sharding import Mesh
-
     if isinstance(num_shards, (tuple, list)):
         if model is not None:
             raise ValueError("pass either a (shards, model) tuple or model=")
@@ -66,13 +85,12 @@ def make_staging_mesh(
             raise ValueError(
                 f"asked for {n} shards but only {len(devs)} devices"
             )
-        return Mesh(np.asarray(devs[:n]), (axis,))
+        return make_mesh((n,), (axis,), devices=devs[:n])
     if n < 1 or n * model > len(devs):
         raise ValueError(
             f"asked for {n}x{model} mesh but only {len(devs)} devices"
         )
-    grid = np.asarray(devs[: n * model]).reshape(n, model)
-    return Mesh(grid, (axis, model_axis))
+    return make_mesh((n, model), (axis, model_axis), devices=devs[: n * model])
 
 
 def make_local_mesh(axes=("data", "model"), shape=None):
@@ -88,4 +106,4 @@ def make_local_mesh(axes=("data", "model"), shape=None):
                     m = f
             shape = (n // m, m) if len(axes) == 2 else (1, n // m, m)
     assert int(np.prod(shape)) == n, f"{shape} != {n} devices"
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)

@@ -53,6 +53,9 @@ def main():
     args = ap.parse_args()
 
     if args.devices:
+        # forced host devices are a CPU feature; on a chip they would hide it
+        if os.environ.get("JAX_PLATFORMS") != "cpu":
+            ap.error("--devices forces host CPU devices: set JAX_PLATFORMS=cpu")
         os.environ["XLA_FLAGS"] = (
             f"--xla_force_host_platform_device_count={args.devices} "
             + os.environ.get("XLA_FLAGS", "")
@@ -65,7 +68,9 @@ def main():
     from ..configs import get_config
     from ..models.transformer import init_params
     from ..serve.engine import ServeEngine
+    from .compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     cfg = get_config(args.arch, reduced=args.reduced)
     params = init_params(cfg, jax.random.PRNGKey(0))
     engine = ServeEngine(

@@ -1,7 +1,7 @@
 """Training launcher.
 
   PYTHONPATH=src python -m repro.launch.train --arch llama3-8b --reduced \
-      --steps 100 --batch 8 --seq 128 --ckpt-dir /tmp/ckpt
+      --steps 100 --batch 8 --seq 128 --ckpt-dir ckpt
 
 On a real pod this binary runs per host (jax.distributed.initialize is
 called when JAX_COORDINATOR is set) with the production mesh; in this
@@ -32,6 +32,9 @@ def main():
     args = ap.parse_args()
 
     if args.devices:
+        # forced host devices are a CPU feature; on a chip they would hide it
+        if os.environ.get("JAX_PLATFORMS") != "cpu":
+            ap.error("--devices forces host CPU devices: set JAX_PLATFORMS=cpu")
         os.environ["XLA_FLAGS"] = (
             f"--xla_force_host_platform_device_count={args.devices} "
             + os.environ.get("XLA_FLAGS", "")
@@ -57,7 +60,10 @@ def main():
     from ..optim.schedule import cosine_schedule
     from ..train.loop import TrainLoop
     from ..train.step import make_train_step
+    from .compile_cache import enable_compile_cache
     from .mesh import make_local_mesh
+
+    enable_compile_cache()
 
     if args.sable:
         from ..configs import llama3_8b

@@ -463,7 +463,7 @@ def choose_matmul_strategy(
 
     timings: dict[str, float] = {}
     if len(candidates) > 1 and allow_bench:
-        from ..core.autotune import measure
+        from ..core.autotune import measure, record_candidate_failure
 
         rng = np.random.default_rng(0)
         x = jnp.asarray(
@@ -478,8 +478,8 @@ def choose_matmul_strategy(
             fn = jax.jit(lambda x, t, _f=_MATMUL_IMPLS[name]: _f(x, t, pattern))
             try:
                 timings[name] = measure(fn, x, tiles, warmup=warmup, iters=iters)
-            except Exception:
-                continue
+            except Exception as e:
+                record_candidate_failure(name, e)
         best = min(timings, key=timings.get) if timings else "grouped"
         source = "measured" if timings else "heuristic"
     else:

@@ -14,10 +14,8 @@ def run_with_devices(script: str, n: int = 8) -> str:
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n}"
     env["PYTHONPATH"] = SRC
-    # pin the child to CPU explicitly: the forced host devices are a CPU
-    # feature, and leaving the platform unset makes jax PROBE for
-    # accelerator plugins first — on an image with the TPU toolchain
-    # installed that probe idles for minutes before falling back
+    # tests run on the CPU, and forced host devices are a CPU feature; a
+    # child must never reach for a chip (only one process may hold it)
     env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run(
         [sys.executable, "-c", textwrap.dedent(script)],
@@ -55,6 +53,7 @@ def test_pjit_train_step_multidevice_matches_single():
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs import get_config
+        from repro.launch.mesh import make_mesh
         from repro.models import init_params
         from repro.optim.adamw import AdamWConfig, adamw_init
         from repro.train.step import make_train_step
@@ -77,7 +76,7 @@ def test_pjit_train_step_multidevice_matches_single():
         # single device reference
         p1, o1, m1 = jax.jit(step)(params, opt, batch, jnp.int32(0))
 
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         ps = make_shardings(mesh, pc, param_specs(cfg, params))
         os_ = {"mu": ps, "nu": ps, "count": NamedSharding(mesh, P())}
         bs = make_shardings(mesh, pc, batch_specs(cfg, batch))
@@ -105,6 +104,7 @@ def test_moe_ep_multidevice_matches_single():
         import jax, jax.numpy as jnp, numpy as np, dataclasses
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs import get_config
+        from repro.launch.mesh import make_mesh
         from repro.models import init_params, forward_train
         from repro.distributed.sharding import (ParallelConfig, param_specs,
             batch_specs, make_shardings)
@@ -119,7 +119,7 @@ def test_moe_ep_multidevice_matches_single():
         ref, _ = jax.jit(lambda p, b: forward_train(p, cfg, b))(params, batch)
 
         pc = ParallelConfig()
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         ps = make_shardings(mesh, pc, param_specs(cfg, params))
         bs = make_shardings(mesh, pc, batch_specs(cfg, batch))
         with activation_sharding(mesh, pc):
@@ -140,14 +140,15 @@ def test_elastic_checkpoint_across_mesh_shapes(tmp_path):
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.checkpoint.manager import save_checkpoint, restore_checkpoint
+        from repro.launch.mesh import make_mesh
 
         tree = {{"w": jnp.arange(64.0).reshape(8, 8)}}
-        mesh_a = jax.make_mesh((4, 2), ("data", "model"))
+        mesh_a = make_mesh((4, 2), ("data", "model"))
         sh_a = {{"w": NamedSharding(mesh_a, P("data", "model"))}}
         t_a = jax.device_put(tree, sh_a)
         save_checkpoint(r"{tmp_path}", 3, t_a)
 
-        mesh_b = jax.make_mesh((2, 4), ("data", "model"))
+        mesh_b = make_mesh((2, 4), ("data", "model"))
         sh_b = {{"w": NamedSharding(mesh_b, P("model", "data"))}}
         t_b, step, _ = restore_checkpoint(r"{tmp_path}", tree, shardings=sh_b)
         assert step == 3
@@ -165,10 +166,11 @@ def test_dryrun_cells_on_small_mesh():
         import jax, dataclasses
         from repro.configs import get_config, SHAPES
         from repro.launch.dryrun import build_cell
+        from repro.launch.mesh import make_mesh
         from repro.distributed.sharding import ParallelConfig
         from repro.distributed.ctx import activation_sharding
         pc = ParallelConfig()
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         for arch in ["llama3-8b", "jamba-1.5-large-398b", "seamless-m4t-large-v2"]:
             cfg = get_config(arch, reduced=True)
             for shape_name in ["train_4k", "prefill_32k", "decode_32k"]:
@@ -187,7 +189,8 @@ def test_hlo_analyzer_counts_scan_trips():
         import jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.launch.hlo_stats import analyze_hlo
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         w = jax.ShapeDtypeStruct((4, 256, 256), jnp.bfloat16)
         x = jax.ShapeDtypeStruct((64, 256), jnp.bfloat16)
         def f(w, x):

@@ -129,9 +129,11 @@ def test_checkpoint_resharding_restore(tmp_path):
     """Elastic restore: load with explicit shardings for the current mesh."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
+    from repro.launch.mesh import make_mesh
+
     t = {"w": jnp.arange(8.0)}
     save_checkpoint(str(tmp_path), 0, t)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     sh = {"w": NamedSharding(mesh, P("data"))}
     restored, _, _ = restore_checkpoint(str(tmp_path), t, shardings=sh)
     assert restored["w"].sharding == sh["w"]
