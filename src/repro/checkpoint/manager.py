@@ -22,7 +22,6 @@ import json
 import os
 import shutil
 import threading
-import time
 from typing import Optional
 
 import jax
@@ -134,17 +133,14 @@ class CheckpointManager:
         self.directory = directory
         self.keep = keep_last_k
         self._thread: Optional[threading.Thread] = None
-        self.save_times: list[float] = []
 
     def save_async(self, step: int, tree, extra: Optional[dict] = None):
         self.wait()  # one in-flight save
         host_tree = jax.tree.map(lambda l: np.asarray(jax.device_get(l)), tree)
 
         def work():
-            t0 = time.perf_counter()
             save_checkpoint(self.directory, step, host_tree, extra)
             self._gc()
-            self.save_times.append(time.perf_counter() - t0)
 
         self._thread = threading.Thread(target=work, daemon=True)
         self._thread.start()

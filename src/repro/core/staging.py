@@ -250,7 +250,10 @@ class StagedKernel:
             self._gather_vbr = vbr
         self.stage0_time = time.perf_counter() - t0
         self.compile_time = 0.0
-        self._fn = jax.jit(self._build())
+        fn = self._build()
+        # a stable program name: jit_sable_<kind>_<backend> in a trace
+        fn.__name__ = fn.__qualname__ = f"sable_{kind}_{self.backend}"
+        self._fn = jax.jit(fn)
 
     # ------------------------------------------------------------------ #
     def _build(self) -> Callable:
@@ -263,10 +266,12 @@ class StagedKernel:
             if coo is None:
                 return y
             rows, cols, vidx = (jnp.asarray(a) for a in coo)
-            v = val[vidx]
-            if kind == "spmv":
-                return y.at[rows].add(v * x[cols])
-            return y.at[rows].add(v[:, None] * x[cols])
+            with jax.named_scope("sable.pack"):
+                v = val[vidx]
+            with jax.named_scope("sable.multiply"):
+                if kind == "spmv":
+                    return y.at[rows].add(v * x[cols])
+                return y.at[rows].add(v[:, None] * x[cols])
 
         if backend == "unrolled":
             descs = self.descs
@@ -276,10 +281,12 @@ class StagedKernel:
                     val, x = val.astype(dtype_cast), x.astype(dtype_cast)
                 y = jnp.zeros(self._out_shape(x), dtype=x.dtype)
                 for d in descs:  # one slice+dot per block (paper codegen)
-                    blk = val[d.val_off : d.val_off + d.h * d.w]
-                    a = blk.reshape(d.w, d.h).T
-                    xs = x[d.col_start : d.col_end]
-                    y = y.at[d.row_start : d.row_end].add(a @ xs)
+                    with jax.named_scope("sable.pack"):
+                        blk = val[d.val_off : d.val_off + d.h * d.w]
+                        a = blk.reshape(d.w, d.h).T
+                    with jax.named_scope("sable.multiply"):
+                        xs = x[d.col_start : d.col_end]
+                        y = y.at[d.row_start : d.row_end].add(a @ xs)
                 return add_coo(y, val, x)
 
             return fn
@@ -291,7 +298,8 @@ class StagedKernel:
                 if dtype_cast is not None:
                     val, x = val.astype(dtype_cast), x.astype(dtype_cast)
                 # sentinel slot 0 = zero (padding reads); OOB rows dropped
-                val1 = jnp.concatenate([jnp.zeros((1,), val.dtype), val])
+                with jax.named_scope("sable.pack"):
+                    val1 = jnp.concatenate([jnp.zeros((1,), val.dtype), val])
                 if kind == "spmv":
                     x1 = jnp.concatenate([jnp.zeros((1,), x.dtype), x])
                 else:
@@ -300,12 +308,14 @@ class StagedKernel:
                     )
                 y = jnp.zeros(self._out_shape(x), dtype=x.dtype)
                 for c in classes:
-                    a = val1[c.vidx].reshape(-1, c.w, c.h)  # col-major blocks
-                    if kind == "spmv":
-                        part = jnp.einsum("bwh,bw->bh", a, x1[c.xrow])
-                    else:
-                        part = jnp.einsum("bwh,bwn->bhn", a, x1[c.xrow])
-                    y = y.at[c.yrow].add(part, mode="drop")
+                    with jax.named_scope("sable.pack"):
+                        a = val1[c.vidx].reshape(-1, c.w, c.h)  # col-major blocks
+                    with jax.named_scope("sable.multiply"):
+                        if kind == "spmv":
+                            part = jnp.einsum("bwh,bw->bh", a, x1[c.xrow])
+                        else:
+                            part = jnp.einsum("bwh,bwn->bhn", a, x1[c.xrow])
+                        y = y.at[c.yrow].add(part, mode="drop")
                 return add_coo(y, val, x)
 
             return fn
@@ -324,38 +334,40 @@ class StagedKernel:
                 if prepack:
                     tiles = val  # caller already packed via self.pack()
                 else:
-                    v1 = jnp.concatenate(
-                        [jnp.zeros((1,), x.dtype), val.astype(x.dtype)]
-                    )
-                    tiles = v1[jnp.asarray(tiled.val_gather)].reshape(
-                        tiled.n_tiles, tiled.tm, tiled.tk
-                    )
-                if kind == "spmv":
-                    x1 = jnp.concatenate([jnp.zeros((1,), x.dtype), x])
-                    xp = x1[jnp.asarray(tiled.x_src)]
-                    yp = kops.bsr_spmv(
-                        tiles,
-                        jnp.asarray(tiled.row_ids),
-                        jnp.asarray(tiled.col_ids),
-                        xp,
-                        m_pad=tiled.m_pad,
-                        interpret=interpret,
-                    )
-                else:
-                    x1 = jnp.concatenate(
-                        [jnp.zeros((1, x.shape[1]), x.dtype), x], axis=0
-                    )
-                    xp = x1[jnp.asarray(tiled.x_src)]
-                    yp = kops.bsr_spmm(
-                        tiles,
-                        jnp.asarray(tiled.row_ids),
-                        jnp.asarray(tiled.col_ids),
-                        xp,
-                        m_pad=tiled.m_pad,
-                        bn=bn,
-                        interpret=interpret,
-                    )
-                y = yp[jnp.asarray(tiled.y_src)]
+                    with jax.named_scope("sable.pack"):
+                        v1 = jnp.concatenate(
+                            [jnp.zeros((1,), x.dtype), val.astype(x.dtype)]
+                        )
+                        tiles = v1[jnp.asarray(tiled.val_gather)].reshape(
+                            tiled.n_tiles, tiled.tm, tiled.tk
+                        )
+                with jax.named_scope("sable.multiply"):
+                    if kind == "spmv":
+                        x1 = jnp.concatenate([jnp.zeros((1,), x.dtype), x])
+                        xp = x1[jnp.asarray(tiled.x_src)]
+                        yp = kops.bsr_spmv(
+                            tiles,
+                            jnp.asarray(tiled.row_ids),
+                            jnp.asarray(tiled.col_ids),
+                            xp,
+                            m_pad=tiled.m_pad,
+                            interpret=interpret,
+                        )
+                    else:
+                        x1 = jnp.concatenate(
+                            [jnp.zeros((1, x.shape[1]), x.dtype), x], axis=0
+                        )
+                        xp = x1[jnp.asarray(tiled.x_src)]
+                        yp = kops.bsr_spmm(
+                            tiles,
+                            jnp.asarray(tiled.row_ids),
+                            jnp.asarray(tiled.col_ids),
+                            xp,
+                            m_pad=tiled.m_pad,
+                            bn=bn,
+                            interpret=interpret,
+                        )
+                    y = yp[jnp.asarray(tiled.y_src)]
                 coo_y = add_coo(jnp.zeros_like(y), val.reshape(-1), x) if coo else None
                 return y if coo_y is None else y + coo_y
 
@@ -417,10 +429,11 @@ class StagedKernel:
     def pack(self, val: jnp.ndarray) -> jnp.ndarray:
         """Prepack the runtime values into tiles (amortized across calls)."""
         assert self.tiled is not None, "pack() is for the pallas backend"
-        v1 = jnp.concatenate([jnp.zeros((1,), val.dtype), val])
-        return v1[jnp.asarray(self.tiled.val_gather)].reshape(
-            self.tiled.n_tiles, self.tiled.tm, self.tiled.tk
-        )
+        with jax.named_scope("sable.pack"):
+            v1 = jnp.concatenate([jnp.zeros((1,), val.dtype), val])
+            return v1[jnp.asarray(self.tiled.val_gather)].reshape(
+                self.tiled.n_tiles, self.tiled.tm, self.tiled.tk
+            )
 
     def __call__(self, val, x):
         return self._fn(val, x)

@@ -63,6 +63,7 @@ from typing import Dict, List, Optional
 import numpy as np
 import jax
 
+from .. import obs
 from ..models.config import ModelConfig
 from ..models.transformer import init_cache
 
@@ -496,23 +497,32 @@ class PagedKVCache:
     # ------------------------------------------------------------------ #
     # writes
     # ------------------------------------------------------------------ #
-    def write_prefill(self, rid: str, cache, length: int, start: int = 0) -> None:
+    def write_prefill(self, rid: str, cache, length: int, start: int = 0) -> int:
         """Copy a dense single-sequence cache (leaves ``(repeat, 1, S, ...)``
         with ``S >= length``) into this sequence's pages + state.  With
         ``start > 0`` only positions ``[start, length)`` are written —
-        chunked prefill and the tail after a shared prefix."""
-        self.write_span(rid, cache, start, length)
+        chunked prefill and the tail after a shared prefix.  Returns the
+        bytes read back from the device (see ``write_span``)."""
+        return self.write_span(rid, cache, start, length)
 
-    def write_span(self, rid: str, cache, start: int, end: int) -> None:
+    def write_span(self, rid: str, cache, start: int, end: int) -> int:
         """Write positions ``[start, end)`` from a dense cache into pages;
         state leaves are replaced wholesale.  Grows the page table to cover
         ``end`` (unzeroed — every grown page is covered by this write plus
-        the explicit tail zero), raising ``PagesExhausted`` when it can't."""
+        the explicit tail zero), raising ``PagesExhausted`` when it can't.
+        Returns the bytes read back from the device: the whole of every
+        leaf that is a device array."""
+        leaves, _ = _flatten(cache)
+        assert len(leaves) == self.num_leaves
+        bytes_d2h = sum(int(x.nbytes) for x in leaves if isinstance(x, jax.Array))
+        with obs.span("kv.write", rid=rid, bytes_d2h=bytes_d2h):
+            self._write_span(rid, leaves, start, end)
+        return bytes_d2h
+
+    def _write_span(self, rid: str, leaves, start: int, end: int) -> None:
         if not self.ensure_capacity(rid, end, zero=False):
             raise PagesExhausted(f"no pages for prefill of {rid!r}")
         self._mark_overwritten(rid, start, end)
-        leaves, _ = _flatten(cache)
-        assert len(leaves) == self.num_leaves
         ps = self.page_size
         old_len = self.seq_len[rid]
         j0, j1 = start // ps, (max(end, start + 1) - 1) // ps
@@ -567,29 +577,31 @@ class PagedKVCache:
         """Materialize the dense batch view for a list of lanes (None =
         empty lane, all zeros).  Leaves come back shaped like
         ``init_cache(cfg, B, view_pages * page_size)``."""
-        B = len(rids)
-        tables = np.asarray([self._full_table(r) for r in rids], np.int64)
-        leaves = []
-        for i in range(self.num_leaves):
-            if self.paged[i]:
-                a = self._arenas[i][tables]  # (B, VP, repeat, ps, ...feat)
-                a = np.moveaxis(a, 2, 0)  # (repeat, B, VP, ps, ...)
-                leaves.append(
-                    a.reshape(a.shape[:2] + (-1,) + a.shape[4:])
-                )
-            else:
-                zero = np.zeros(self._state_shape[i], self._dtypes[i])
-                leaves.append(
-                    np.concatenate(
-                        [
-                            (zero if r is None else self._state[r][i])
-                            for r in rids
-                        ],
-                        axis=1,
+        with obs.span("kv.gather") as sp:
+            B = len(rids)
+            tables = np.asarray([self._full_table(r) for r in rids], np.int64)
+            leaves = []
+            for i in range(self.num_leaves):
+                if self.paged[i]:
+                    a = self._arenas[i][tables]  # (B, VP, repeat, ps, ...feat)
+                    a = np.moveaxis(a, 2, 0)  # (repeat, B, VP, ps, ...)
+                    leaves.append(
+                        a.reshape(a.shape[:2] + (-1,) + a.shape[4:])
                     )
-                    if B
-                    else zero
-                )
+                else:
+                    zero = np.zeros(self._state_shape[i], self._dtypes[i])
+                    leaves.append(
+                        np.concatenate(
+                            [
+                                (zero if r is None else self._state[r][i])
+                                for r in rids
+                            ],
+                            axis=1,
+                        )
+                        if B
+                        else zero
+                    )
+            sp.set_metadata(bytes=sum(int(x.nbytes) for x in leaves))
         return jax.tree_util.tree_unflatten(self.treedef, leaves)
 
     def read_dense(self, rid: str, s_max: Optional[int] = None):

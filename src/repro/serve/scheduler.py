@@ -54,6 +54,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from ..models.config import ModelConfig
 from ..models.transformer import decode_step, init_cache, prefill
 from ..models.transformer import prefill_chunk as _prefill_chunk_fn
@@ -104,7 +105,8 @@ class Request:
 def _make_lane_step(cfg: ModelConfig, paged_mask):
     """Jitted per-step decoder: vmap of the single-sequence decode over
     lanes with per-lane (cache view, position, key, temperature).  Returns
-    (next_token (B,), logits (B, V) f32, written-slice pytree)."""
+    (next_token (B,), logits (B, V) f32, written-slice pytree).  The program
+    is named ``serve_lane_step`` (``jit_serve_lane_step`` in a trace)."""
 
     def one(params, tok, cache_b, pos, rng, temp):
         cache1 = jax.tree.map(lambda a: a[:, None], cache_b)  # re-add B=1
@@ -126,7 +128,9 @@ def _make_lane_step(cfg: ModelConfig, paged_mask):
         )
         return nxt[0], row[0], sl
 
-    return jax.jit(jax.vmap(one, in_axes=(None, 0, 1, 0, 0, 0)))
+    lanes = jax.vmap(one, in_axes=(None, 0, 1, 0, 0, 0))
+    lanes.__name__ = lanes.__qualname__ = "serve_lane_step"
+    return jax.jit(lanes)
 
 
 class ContinuousBatchingScheduler:
@@ -235,6 +239,10 @@ class ContinuousBatchingScheduler:
             "pages_shared": 0,
             "cow_copies": 0,
             "shared_releases": 0,
+            # host<->device bytes of the KV path (see repro.obs)
+            "kv_h2d_bytes": 0,
+            "kv_d2h_bytes": 0,
+            "decode_steps_with_view": 0,
         }
 
     # ------------------------------------------------------------------ #
@@ -448,34 +456,35 @@ class ContinuousBatchingScheduler:
             else:
                 if not self.kv.alloc_seq(req.rid, req.prompt_len, zero=False):
                     return
-                self._prefill_request(req, now)
+                self._prefill_request(req)
                 ev["admitted"].append(req.rid)
             self.queue.pop(qi)
             req.state = RUNNING
             self.stats["admissions"] += 1
             req.metrics.setdefault("admitted_at", now)
             if len(req.tokens) >= req.max_new_tokens:
-                self._finish(req, free[0], now, ev, lane_assigned=False)
+                self._finish(req, free[0], self.clock(), ev, lane_assigned=False)
             else:
                 self.lanes[free[0]] = req
 
-    def _prefill_request(self, req: Request, now: float) -> None:
+    def _prefill_request(self, req: Request) -> None:
         """Whole-prompt prefill at admission — the plain path (both features
         off); frozen by the golden transcript, so it stays byte-stable."""
         P = req.prompt_len
-        cache = init_cache(self.cfg, 1, P)
-        logits, cache = self._prefill(
-            self.params, jnp.asarray(req.prompt[None]), cache
-        )
-        row = logits[:, -1].astype(jnp.float32)  # (1, V)
-        first = int(jnp.argmax(row, axis=-1)[0])
-        self.kv.write_prefill(req.rid, cache, P)
+        with obs.span("serve.prefill", rid=req.rid, tokens=P):
+            cache = init_cache(self.cfg, 1, P)
+            logits, cache = self._prefill(
+                self.params, jnp.asarray(req.prompt[None]), cache
+            )
+            row = logits[:, -1].astype(jnp.float32)  # (1, V)
+            first = int(jnp.argmax(row, axis=-1)[0])
+        self.stats["kv_d2h_bytes"] += self.kv.write_prefill(req.rid, cache, P)
         self.stats["prefill_tokens"] += P
         req.prefilled = P
         req.tokens.append(first)
         if self.record_logits:
             req.logits.append(np.asarray(row[0]))
-        req.metrics.setdefault("first_token_at", now)
+        req.metrics.setdefault("first_token_at", self.clock())
 
     # ------------------------------------------------------------------ #
     # prefix-shared / chunked prefill
@@ -501,11 +510,11 @@ class ContinuousBatchingScheduler:
         if req.prefilled:
             ev["shared"][req.rid] = req.prefilled
         if not self.chunked_prefill:
-            self._prefill_one_chunk(req, now, ev, in_admit=True)
+            self._prefill_one_chunk(req, ev, in_admit=True)
         return True
 
     def _prefill_one_chunk(
-        self, req: Request, now: float, ev: dict, in_admit: bool = False
+        self, req: Request, ev: dict, in_admit: bool = False
     ) -> None:
         """Advance one mid-prefill sequence by one chunk (or the whole
         remaining tail when chunking is off).  The final chunk emits the
@@ -537,25 +546,32 @@ class ContinuousBatchingScheduler:
             self._evict(req, ev)
             return
         dense = self.kv.read_dense(req.rid, s_max=self._prefill_width)
-        logits, dense = self._prefill_chunk(
-            self.params,
-            jnp.asarray(req.prompt[None, start:end]),
-            dense,
-            jnp.int32(start),
-        )
-        self.kv.write_span(req.rid, dense, start, end)
+        h2d = sum(int(x.nbytes) for x in jax.tree.leaves(dense))
+        with obs.span("kv.upload", bytes_h2d=h2d):
+            dense = jax.block_until_ready(jax.device_put(dense))
+        self.stats["kv_h2d_bytes"] += h2d
+        with obs.span("serve.prefill", rid=req.rid, tokens=end - start):
+            logits, dense = self._prefill_chunk(
+                self.params,
+                jnp.asarray(req.prompt[None, start:end]),
+                dense,
+                jnp.int32(start),
+            )
+            if end == P:
+                row = logits[:, -1].astype(jnp.float32)  # (1, V)
+                first = int(jnp.argmax(row, axis=-1)[0])
+        self.stats["kv_d2h_bytes"] += self.kv.write_span(req.rid, dense, start, end)
         req.prefilled = end
         self.stats["prefill_tokens"] += end - start
         self.stats["prefill_chunks"] += 1
         ev["prefill"][req.rid] = [start, end]
         if end == P:
-            row = logits[:, -1].astype(jnp.float32)  # (1, V)
-            req.tokens.append(int(jnp.argmax(row, axis=-1)[0]))
+            req.tokens.append(first)
             if self.record_logits:
                 req.logits.append(np.asarray(row[0]))
-            req.metrics.setdefault("first_token_at", now)
+            req.metrics.setdefault("first_token_at", self.clock())
 
-    def _advance_prefills(self, now: float, ev: dict) -> None:
+    def _advance_prefills(self, ev: dict) -> None:
         """One chunk per mid-prefill lane per step, oldest arrival first —
         interleaved with decode so long prompts never stall running lanes."""
         if not self.chunked_prefill:
@@ -572,14 +588,14 @@ class ContinuousBatchingScheduler:
             req = self.lanes[i]
             if req is None or req.prefilled >= req.prompt_len:
                 continue  # evicted by an earlier lane's page pressure
-            self._prefill_one_chunk(req, now, ev)
+            self._prefill_one_chunk(req, ev)
             # max_new_tokens == 1: the final chunk's token is the output
             if (
                 self.lanes[i] is req
                 and req.tokens
                 and len(req.tokens) >= req.max_new_tokens
             ):
-                self._finish(req, i, now, ev)
+                self._finish(req, i, self.clock(), ev)
 
     def _release_parked_shared_one(self) -> bool:
         """Terminal-pressure escape valve: demote the youngest parked
@@ -651,6 +667,12 @@ class ContinuousBatchingScheduler:
     # the scheduling iteration
     # ------------------------------------------------------------------ #
     def step(self) -> dict:
+        with obs.span("serve.step") as sp:
+            ev = self._step()
+            sp.set_metadata(running=len(ev["running"]), admitted=len(ev["admitted"]))
+        return ev
+
+    def _step(self) -> dict:
         now = self.clock()
         ev = {
             "step": self.stats["steps"],
@@ -668,8 +690,9 @@ class ContinuousBatchingScheduler:
             ev["shared"] = {}
             ev["prefill"] = {}
         self._stage_cold(ev)
-        self._admit(now, ev)
-        self._advance_prefills(now, ev)
+        with obs.span("serve.admit"):
+            self._admit(now, ev)
+        self._advance_prefills(ev)
         active = self._ensure_growth(ev)
         ev["running"] = [self.lanes[i].rid for i in active]
         ev["page_tables"] = {
@@ -677,7 +700,8 @@ class ContinuousBatchingScheduler:
             for i in active
         }
         if active:
-            self._decode_once(active, ev)
+            with obs.span("serve.decode", lanes=len(active)):
+                self._decode_once(active, ev)
         self.stats["steps"] += 1
         for k, v in self.kv.share_stats.items():
             self.stats[k] = v
@@ -710,60 +734,75 @@ class ContinuousBatchingScheduler:
         view = self.kv.gather(
             [lane_reqs[i].rid if i in lane_reqs else None for i in range(B)]
         )
-        nxt, logits, slices = self._lane_step(
-            self.params,
-            jnp.asarray(toks),
-            view,
-            jnp.asarray(pos),
-            jnp.asarray(np.stack(keys)),
-            jnp.asarray(temps),
-        )
-        nxt = np.asarray(nxt)
-        logits = np.asarray(logits)
-        flat, _ = jax.tree_util.tree_flatten(slices)
-        flat = [np.asarray(leaf) for leaf in flat]
+        # the step cannot start before its view is on the device, so waiting
+        # for the upload here adds no wait; it only names the transfer
+        h2d = sum(int(x.nbytes) for x in jax.tree.leaves(view))
+        with obs.span("kv.upload", bytes_h2d=h2d):
+            view = jax.block_until_ready(jax.device_put(view))
+        self.stats["kv_h2d_bytes"] += h2d
+        self.stats["decode_steps_with_view"] += 1
+        with obs.span("serve.lane_step"):
+            nxt, logits, slices = jax.block_until_ready(
+                self._lane_step(
+                    self.params,
+                    jnp.asarray(toks),
+                    view,
+                    jnp.asarray(pos),
+                    jnp.asarray(np.stack(keys)),
+                    jnp.asarray(temps),
+                )
+            )
+        flat = jax.tree.leaves(slices)
+        kv_bytes = sum(int(x.nbytes) for x in flat)
+        d2h = int(nxt.nbytes) + int(logits.nbytes) + kv_bytes
+        with obs.span("serve.readback", bytes_d2h=d2h, kv_bytes=kv_bytes):
+            nxt = np.asarray(nxt)
+            logits = np.asarray(logits)
+            flat = [np.asarray(leaf) for leaf in flat]
+        self.stats["kv_d2h_bytes"] += kv_bytes
         now = self.clock()
-        for i in active:
-            req = lane_reqs[i]
-            if self.lanes[i] is not req:
-                # parked by an earlier lane's page pressure before its own
-                # append: this step's write is lost, so rewind the rng split
-                # — the redone step after resume samples identically
-                req.rng = rng_before[i]
-                continue
-            slices_i = [leaf[i] for leaf in flat]
-            while True:
-                try:
-                    self.kv.append_token(req.rid, slices_i, int(pos[i]))
-                    break
-                except PagesExhausted:
-                    # COW or growth needed a page mid-append: evict per
-                    # policy (youngest other lane first), then the shared-
-                    # page escape valve, then park this lane losslessly
-                    others = [
-                        r
-                        for r in self.lanes
-                        if r is not None and r is not req
-                    ]
-                    if others:
-                        victim = max(
-                            others, key=lambda r: (r.arrival, r.rid)
-                        )
-                        self._evict(victim, ev)
-                        continue
-                    if self._release_parked_shared_one():
-                        continue
-                    self._evict(req, ev)
+        with obs.span("kv.append", lanes=len(active)):
+            for i in active:
+                req = lane_reqs[i]
+                if self.lanes[i] is not req:
+                    # parked by an earlier lane's page pressure before its own
+                    # append: this step's write is lost, so rewind the rng split
+                    # — the redone step after resume samples identically
                     req.rng = rng_before[i]
-                    break
-            if self.lanes[i] is not req:
-                continue  # parked itself above
-            req.tokens.append(int(nxt[i]))
-            if self.record_logits:
-                req.logits.append(logits[i])
-            self.stats["decode_tokens"] += 1
-            if len(req.tokens) >= req.max_new_tokens:
-                self._finish(req, i, now, ev)
+                    continue
+                slices_i = [leaf[i] for leaf in flat]
+                while True:
+                    try:
+                        self.kv.append_token(req.rid, slices_i, int(pos[i]))
+                        break
+                    except PagesExhausted:
+                        # COW or growth needed a page mid-append: evict per
+                        # policy (youngest other lane first), then the shared-
+                        # page escape valve, then park this lane losslessly
+                        others = [
+                            r
+                            for r in self.lanes
+                            if r is not None and r is not req
+                        ]
+                        if others:
+                            victim = max(
+                                others, key=lambda r: (r.arrival, r.rid)
+                            )
+                            self._evict(victim, ev)
+                            continue
+                        if self._release_parked_shared_one():
+                            continue
+                        self._evict(req, ev)
+                        req.rng = rng_before[i]
+                        break
+                if self.lanes[i] is not req:
+                    continue  # parked itself above
+                req.tokens.append(int(nxt[i]))
+                if self.record_logits:
+                    req.logits.append(logits[i])
+                self.stats["decode_tokens"] += 1
+                if len(req.tokens) >= req.max_new_tokens:
+                    self._finish(req, i, now, ev)
 
     # ------------------------------------------------------------------ #
     def pending(self) -> int:

@@ -566,3 +566,135 @@ def test_sharing_and_chunking_require_fully_paged_cache():
         with pytest.raises(ValueError, match="fully-paged"):
             ContinuousBatchingScheduler(cfg, params, max_len=16, **kw)
     ContinuousBatchingScheduler(cfg, params, max_len=16)  # defaults stay fine
+
+
+# ---------------------------------------------------------------------- #
+# spans and counters of the serving step (repro.obs)
+# ---------------------------------------------------------------------- #
+def _traced_host_spans(tmp_path, work):
+    """Run ``work()`` under a real profiler trace; return the program's host
+    spans as (name, start, end, stats dict), in start order."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        work()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(("serve.", "kv.")):
+                    out.append((e.name, e.start_ns, e.start_ns + e.duration_ns,
+                                dict(e.stats)))
+    return sorted(out, key=lambda s: (s[1], -s[2]))
+
+
+def _parent(spans, child):
+    """The innermost other span that encloses ``child``."""
+    inside = [s for s in spans if s is not child and s[1] <= child[1] and child[2] <= s[2]]
+    return min(inside, key=lambda s: s[2] - s[1])[0] if inside else None
+
+
+def test_serving_step_spans_nest_and_count_the_view_bytes(engine, tmp_path):
+    cfg = engine.cfg
+    sched = engine.make_scheduler(page_size=4, max_batch=2)
+    for i, p in enumerate(_prompts(cfg, [5, 3], seed=5)):
+        sched.submit(p, max_new_tokens=5, rid=f"o{i}")
+    sched.submit(_prompts(cfg, [4], seed=6)[0], max_new_tokens=2, rid="o2")
+    sched.step()  # compile outside the trace
+    before = dict(sched.stats)
+    view_bytes = sum(x.nbytes for x in jax.tree.leaves(sched.kv.gather([None] * 2)))
+    spans = _traced_host_spans(tmp_path, sched.run)
+    names = [s[0] for s in spans]
+
+    decode_children = ("kv.gather", "kv.upload", "serve.lane_step", "serve.readback",
+                       "kv.append")
+    for s in spans:
+        if s[0] in decode_children:
+            assert _parent(spans, s) == "serve.decode", s
+        elif s[0] in ("serve.decode", "serve.admit"):
+            assert _parent(spans, s) == "serve.step", s
+        elif s[0] in ("serve.prefill", "kv.write"):
+            assert _parent(spans, s) == "serve.admit", s
+        else:
+            assert s[0] == "serve.step" and _parent(spans, s) is None, s
+    n_decode = names.count("serve.decode")
+    assert n_decode >= 3 and names.count("serve.prefill") == names.count("kv.write") == 1
+    for child in decode_children:
+        assert names.count(child) == n_decode, child
+    assert names.count("serve.step") == sched.stats["steps"] - before["steps"]
+    assert [s[3]["rid"] for s in spans if s[0] in ("serve.prefill", "kv.write")] == ["o2"] * 2
+
+    # the upload is the gathered view, byte for byte
+    assert all(s[3]["bytes_h2d"] == view_bytes for s in spans if s[0] == "kv.upload")
+    assert all(s[3]["bytes"] == view_bytes for s in spans if s[0] == "kv.gather")
+    steps = [s[3] for s in spans if s[0] == "serve.step"]
+    assert [s[3]["lanes"] for s in spans if s[0] == "serve.decode"] == [
+        st["running"] for st in steps if st["running"]]
+    assert sum(st["admitted"] for st in steps) == 1
+
+    # the scheduler's counters are the spans' arguments, summed
+    def grew(key):
+        return sched.stats[key] - before[key]
+
+    assert grew("decode_steps_with_view") == n_decode
+    assert grew("kv_h2d_bytes") == sum(s[3]["bytes_h2d"] for s in spans if s[0] == "kv.upload")
+    assert grew("kv_d2h_bytes") == sum(
+        s[3]["bytes_d2h"] for s in spans if s[0] == "kv.write") + sum(
+        s[3]["kv_bytes"] for s in spans if s[0] == "serve.readback")
+    assert all(s[3]["bytes_d2h"] > s[3]["kv_bytes"] > 0 for s in spans if s[0] == "serve.readback")
+
+
+def test_serving_spans_are_recorded_in_chunked_prefill(engine, tmp_path):
+    cfg = engine.cfg
+    sched = engine.make_scheduler(page_size=4, max_batch=2, chunked_prefill=True,
+                                  prefill_chunk=4)
+    sched.submit(_prompts(cfg, [9], seed=3)[0], max_new_tokens=3, rid="c0")
+    spans = _traced_host_spans(tmp_path, sched.run)
+    chunks = [s for s in spans if s[0] == "serve.prefill"]
+    assert [s[3]["tokens"] for s in chunks] == [4, 4, 1]
+    assert all(_parent(spans, s) in ("serve.step", "serve.admit") for s in chunks)
+    assert sched.stats["kv_h2d_bytes"] == sum(
+        s[3]["bytes_h2d"] for s in spans if s[0] == "kv.upload")
+    assert sched.stats["kv_d2h_bytes"] == sum(
+        s[3]["bytes_d2h"] for s in spans if s[0] == "kv.write") + sum(
+        s[3]["kv_bytes"] for s in spans if s[0] == "serve.readback")
+
+
+def test_first_token_is_stamped_after_its_prefill(engine):
+    """``first_token_at`` is read once the first token exists, after the
+    step's opening clock reading, and never after the request finished."""
+    cfg = engine.cfg
+    readings = []
+    clock = _fake_clock()
+
+    def tick():
+        readings.append(clock())
+        return readings[-1]
+
+    sched = engine.make_scheduler(page_size=4, max_batch=2, clock=tick)
+    for i, p in enumerate(_prompts(cfg, [5, 3], seed=9)):
+        sched.submit(p, max_new_tokens=3 - i, rid=f"f{i}", arrival=0.0)
+    n = len(readings)
+    sched.step()
+    opening = readings[n]
+    for rid in ("f0", "f1"):
+        m = sched.requests[rid].metrics
+        assert m["admitted_at"] == opening
+        assert m["first_token_at"] > opening
+    sched.run()
+    for rid in ("f0", "f1"):
+        m = sched.requests[rid].metrics
+        assert m["first_token_at"] <= m["finished_at"]
+
+
+def test_lane_step_program_has_a_stable_name(engine):
+    sched = engine.make_scheduler(page_size=4, max_batch=2)
+    assert sched._lane_step.__name__ == "serve_lane_step"

@@ -119,6 +119,34 @@ def test_prepack_amortization():
     np.testing.assert_allclose(y, v.to_dense() @ x, rtol=2e-4, atol=2e-4)
 
 
+@pytest.mark.parametrize("kind,backend", [("spmv", "grouped"), ("spmm", "grouped"),
+                                          ("spmv", "pallas"), ("spmv", "unrolled")])
+def test_staged_program_names_its_phases(kind, backend):
+    """The staged program is named ``sable_<kind>_<backend>`` and its value
+    packing and multiply carry the ``sable.pack`` / ``sable.multiply``
+    scopes, which reach the device ops' ``op_name``."""
+    v = _mk(seed=6)
+    opts = StagingOptions(backend=backend, tile=(8, 16), interpret=True)
+    if kind == "spmv":
+        k, x = stage_spmv(v, opts), jnp.ones(v.shape[1], jnp.float32)
+    else:
+        k, x = stage_spmm(v, 4, opts), jnp.ones((v.shape[1], 4), jnp.float32)
+    lowered = k._fn.lower(jnp.asarray(v.val), x)
+    text = lowered.as_text(debug_info=True)
+    assert "sable.pack" in text and "sable.multiply" in text
+    hlo = lowered.compile().as_text()
+    assert hlo.startswith(f"HloModule jit_sable_{kind}_{backend}")
+    assert 'op_name="jit(sable_' in hlo and "/sable.pack/" in hlo
+
+
+def test_prepacked_tiles_carry_the_pack_scope():
+    v = _mk(seed=7)
+    k = stage_spmv(v, StagingOptions(backend="pallas", tile=(8, 16), interpret=True,
+                                     prepack=True))
+    text = jax.jit(k.pack).lower(jnp.asarray(v.val)).as_text(debug_info=True)
+    assert "sable.pack" in text
+
+
 def test_partition_block_rows_balance():
     """Paper IV-D: greedy grouping balances nnz-block load."""
     v = _mk(seed=6, rows=200, cols=200, rs=20, cs=10, nb=80)
