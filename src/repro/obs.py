@@ -16,22 +16,38 @@ arguments:
       serve.admit
         serve.prefill   rid, tokens: the jitted prefill of ``tokens`` prompt
                         positions and the argmax that waits for it
-        kv.write        rid, bytes_d2h: the prefill cache read back and
-                        copied into the request's pages
+        kv.write        rid, bytes_d2h: the prefill cache scattered into the
+                        request's pages on the device; bytes_d2h counts the
+                        state leaves read back (0 for a fully-paged cache)
       serve.prefill     (chunked prefill: one chunk per mid-prefill lane,
-      kv.write           directly under serve.step)
+      kv.upload          directly under serve.step; kv.upload's bytes_h2d
+      kv.write           counts host leaves of the chunk's cache, none when
+                         the cache is fully paged: it is gathered on the
+                         device)
       serve.decode      lanes
-        kv.gather       bytes: the dense batch view built on the host
-        kv.upload       bytes_h2d: the view put on the device, waited for
-        serve.lane_step the jitted lane step's dispatch and its outputs' wait
-        serve.readback  bytes_d2h: next tokens, logits and KV slices read
-                        back; kv_bytes: the KV slices among them
-        kv.append       lanes: the per-lane appends into the pages
+        kv.gather       bytes: each lane's write resolved on the host
+                        (growth, copy-on-write, eviction under page
+                        pressure) and the int32 page table built; bytes is
+                        the table's
+        kv.upload       bytes_h2d: the page table, tokens, positions, keys,
+                        temperatures, write pages, queued page copies and
+                        state leaves put on the device, waited for
+        serve.lane_step the jitted lane step (gathers the views from the
+                        device arenas, decodes, writes the new KV into the
+                        pages) and the wait for its outputs
+        serve.readback  bytes_d2h: next tokens, logits and state leaves
+                        read back; kv_bytes: the state leaves among them
+        kv.append       lanes: the host bookkeeping after the step
+                        (``seq_len``, state leaves, tokens, finishes)
 
 Every span of one request carries its ``rid``.  The same byte counts go
-into the scheduler's ``stats`` (``kv_h2d_bytes``: views uploaded;
-``kv_d2h_bytes``: KV slices and prefill caches read back;
-``decode_steps_with_view``), so they are seen without a profiler too.
+into the scheduler's ``stats`` (``kv_h2d_bytes``: step arguments and KV put
+on the device; ``kv_d2h_bytes``: KV read back; ``decode_steps_with_view``:
+decode steps dispatched), so they are seen without a profiler too.
+``kv_parked_bytes`` counts the KV bytes copied by eviction, resume and the
+release of a parked sequence's shared pages — the only KV that still
+crosses, and only under page pressure; it is part of ``kv_d2h_bytes``
+(evict, release) and ``kv_h2d_bytes`` (resume) as well.
 
 The staged sparse programs name their phases with ``jax.named_scope``
 instead (trace-time only, free at run time): ``sable.pack`` around the

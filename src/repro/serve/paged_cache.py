@@ -31,21 +31,32 @@ paged automatically.  (Prefix sharing requires a fully-paged cache: state
 leaves summarize the whole prompt and cannot be reconstructed from a
 shared page span — the scheduler enforces this.)
 
-Arenas are host (numpy) arrays: the scheduler gathers the active lanes
-into a dense ``(repeat, B, S_view, ...)`` batch view per decode step (the
-page-table indirection happens here, outside the jitted step) and scatters
-each lane's newly written position back afterwards.  Page id
-``num_pages`` is a reserved always-zero page used to pad the view for
-lanes that have not allocated that far yet, so a gathered view is
-bit-identical to the dense reference cache over every written position
-and zero beyond it.
+Arenas are device arrays, held for the cache's lifetime; the host keeps
+the books (allocator, page tables, refcounts, prefix digests, ``seq_len``,
+parked blobs).  A decode step passes the arenas and an int32 page table
+``(B, view_pages)`` (``gather``) to one jitted program that gathers the
+dense ``(repeat, B, S_view, ...)`` batch view inside its trace, decodes,
+and writes each lane's new position back into its page (``gather_view``,
+``write_slots``).  The page each lane writes is resolved on the host
+before the step (``append_token(rid, None, pos)``: growth, copy-on-write),
+so page exhaustion is met before the device runs, never after.  Page
+copies (COW) and the zeroing of recycled pages (a copy of the zero page)
+are queued and applied in one batch: by the next decode step itself, or
+by ``flush`` before any other device read or write of the arenas.
+Prefill writes scatter the prefill's device cache into pages on the
+device.  Page id ``num_pages`` is a reserved always-zero page used to pad
+the table for lanes that have not allocated that far yet, so a gathered
+view is bit-identical to the dense reference cache over every written
+position and zero beyond it.  Leaves without a sequence axis (state) stay
+per sequence on the host.
 
-Eviction parks a sequence's *private* pages + state on the host and frees
-them; pages shared with other sequences (refcount > 1) are retained under
-the parked sequence's reference — they are already resident, so parking
-copies nothing and frees nothing for them.  ``resume`` reallocates the
-private pages and restores bit-for-bit, so a preempted sequence continues
-decoding losslessly.  ``release_parked_shared`` demotes a parked
+Eviction parks a sequence's *private* pages + state on the host (the only
+KV that crosses between host and device, counted in ``parked_bytes``) and
+frees them; pages shared with other sequences (refcount > 1) are retained
+under the parked sequence's reference — they are already resident, so
+parking copies nothing and frees nothing for them.  ``resume`` reallocates
+the private pages and restores bit-for-bit, so a preempted sequence
+continues decoding losslessly.  ``release_parked_shared`` demotes a parked
 sequence's retained shared pages to host copies when the arena is under
 terminal pressure.
 
@@ -56,18 +67,111 @@ instead of dying.
 from __future__ import annotations
 
 import collections
+import functools
 import hashlib
 import math
 from typing import Dict, List, Optional
 
 import numpy as np
 import jax
+import jax.numpy as jnp
 
 from .. import obs
 from ..models.config import ModelConfig
 from ..models.transformer import init_cache
 
-__all__ = ["PageAllocator", "PagedKVCache", "PagesExhausted"]
+__all__ = [
+    "PageAllocator", "PagedKVCache", "PagesExhausted", "copy_pages",
+    "gather_view", "write_slots",
+]
+
+
+# ---------------------------------------------------------------------- #
+# device-side page operations: plain functions for use inside a trace
+# (the decode step), and jitted with the arenas donated for the cache's
+# own updates.  An arena is (num_pages + 1, repeat, page_size, ...feat);
+# a page id past the zero page is out of range and its write is dropped.
+# ---------------------------------------------------------------------- #
+def gather_view(arena, table):
+    """The dense ``(repeat, B, VP * page_size, ...feat)`` view of ``arena``
+    through the int32 page ``table`` ``(B, VP)``."""
+    a = jnp.moveaxis(arena[table], 2, 0)  # (repeat, B, VP, ps, ...feat)
+    return a.reshape(a.shape[:2] + (-1,) + a.shape[4:])
+
+
+def copy_pages(arenas, src, dst):
+    """``arena[dst] = arena[src]`` in every arena, every read before any
+    write; out-of-range ``dst`` entries are padding and write nothing."""
+    return [a.at[dst].set(a[src], mode="drop") for a in arenas]
+
+
+def write_slots(arenas, pages, positions, slices):
+    """Write lane ``b``'s ``slices[k][b]`` ``(repeat, ...feat)`` at
+    ``positions[b] % page_size`` of page ``pages[b]`` in arena ``k``."""
+    out = []
+    for a, sl in zip(arenas, slices):
+        off = positions % a.shape[2]
+        out.append(a.at[pages, :, off].set(sl.astype(a.dtype), mode="drop"))
+    return out
+
+
+_copy_pages = jax.jit(copy_pages, donate_argnums=0)
+_write_slots = jax.jit(write_slots, donate_argnums=0)
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _put_pages(arenas, pages, blocks):
+    return [a.at[pages].set(b, mode="drop") for a, b in zip(arenas, blocks)]
+
+
+@jax.jit
+def _take_pages(arenas, pages):
+    return [a[pages] for a in arenas]
+
+
+@functools.partial(jax.jit, static_argnames="s_max")
+def _read_pages(arenas, table, length, *, s_max):
+    """Dense ``(repeat, 1, s_max, ...feat)`` leaves of one sequence through
+    its page ``table``, zero from ``length`` on."""
+    out = []
+    for a in arenas:
+        v = gather_view(a, table[None])[:, :, :s_max]
+        keep = (jnp.arange(s_max) < length).reshape((1, 1, -1) + (1,) * (v.ndim - 3))
+        out.append(jnp.where(keep, v, jnp.zeros((), v.dtype)))
+    return out
+
+
+@jax.jit
+def _view(arenas, table):
+    return [gather_view(a, table) for a in arenas]
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _write_span_pages(arenas, leaves, pages, j0, start, end, zero_tail):
+    """Write positions ``[start, end)`` of the dense single-sequence
+    ``leaves`` ``(repeat, 1, S, ...feat)`` into ``pages`` (slots ``j0,
+    j0+1, ...``; padded past the zero page).  Positions of those pages
+    below ``start`` keep their bytes; positions from ``end`` on are zeroed
+    when ``zero_tail``, else kept."""
+    out = []
+    for a, leaf in zip(arenas, leaves):
+        ps, n, S = a.shape[2], pages.shape[0], leaf.shape[2]
+        pos = (j0 + jnp.arange(n))[:, None] * ps + jnp.arange(ps)[None, :]
+        src = jnp.take(leaf[:, 0], jnp.clip(pos, 0, S - 1), axis=1)
+        src = jnp.moveaxis(src, 1, 0).astype(a.dtype)  # (n, repeat, ps, ...)
+        old = a.at[pages].get(mode="clip")
+        shape = (n, 1, ps) + (1,) * (a.ndim - 3)
+        written = ((pos >= start) & (pos < end)).reshape(shape)
+        zeroed = (zero_tail & (pos >= end)).reshape(shape)
+        new = jnp.where(written, src, jnp.where(zeroed, jnp.zeros((), a.dtype), old))
+        out.append(a.at[pages].set(new, mode="drop"))
+    return out
+
+
+def _bucket(n: int) -> int:
+    """Padded length of a batch of ``n`` page operations: a power of two,
+    at least 8, so a handful of programs serves every count."""
+    return max(8, 1 << max(0, n - 1).bit_length())
 
 
 class PagesExhausted(RuntimeError):
@@ -180,7 +284,7 @@ class PagedKVCache:
         self.num_leaves = len(tb)
         self.paged: List[bool] = []
         self.seq_axis: List[Optional[int]] = []
-        self._arenas: List[Optional[np.ndarray]] = []
+        self._arenas: List[Optional[jax.Array]] = []
         self._state_shape: List[Optional[tuple]] = []
         self._dtypes = []
         for la, lb in zip(ta, tb):
@@ -198,9 +302,9 @@ class PagedKVCache:
                 feat = tuple(lb.shape[3:])
                 repeat = lb.shape[0]
                 self._arenas.append(
-                    np.zeros(
+                    jnp.zeros(
                         (num_pages + 1, repeat, self.page_size) + feat,
-                        np.dtype(lb.dtype),
+                        lb.dtype,
                     )
                 )
                 self._state_shape.append(None)
@@ -229,13 +333,66 @@ class PagedKVCache:
             "cow_copies": 0,
         }
         self.zero_writes = 0  # pages zeroed (prefill-path bandwidth audit)
+        # queued page copies, dst -> src (zeroing copies the zero page)
+        self._pending: Dict[int, int] = {}
+        # writes the next decode step makes: rid -> (page, position)
+        self._step_writes: Dict[str, tuple] = {}
+        self.parked_bytes = 0  # KV bytes copied by evict / resume / release
 
     # ------------------------------------------------------------------ #
-    # mask pytree for the lane decoder (True = leaf has a sequence axis)
+    # the device arenas and their queued page copies
     # ------------------------------------------------------------------ #
     @property
-    def paged_mask(self):
-        return jax.tree_util.tree_unflatten(self.treedef, list(self.paged))
+    def arenas(self) -> List[jax.Array]:
+        """The paged leaves' arenas, in leaf order (empty for a cache with
+        no sequence axis).  A program that takes them donated hands the
+        updated ones back through the setter."""
+        return [a for a in self._arenas if a is not None]
+
+    @arenas.setter
+    def arenas(self, new) -> None:
+        it = iter(new)
+        self._arenas = [None if a is None else next(it) for a in self._arenas]
+
+    def take_copies(self, n: int):
+        """The queued page copies as int32 ``(src, dst)`` of length ``n``
+        (padding copies the zero page out of range), for a program that
+        applies them (``copy_pages``); the queue is emptied.  A queue
+        longer than ``n`` is flushed here first."""
+        if len(self._pending) > n:
+            self.flush()
+        src = np.full(n, self.zero_page, np.int32)
+        dst = np.full(n, self.zero_page + 1, np.int32)
+        for k, (d, s) in enumerate(self._pending.items()):
+            dst[k], src[k] = d, s
+        self._pending.clear()
+        return src, dst
+
+    def flush(self) -> None:
+        """Apply the queued page copies on the device, in one call."""
+        if self._pending:
+            src, dst = self.take_copies(_bucket(len(self._pending)))
+            self.arenas = _copy_pages(self.arenas, src, dst)
+
+    def _queue_copy(self, dst: int, src: int) -> None:
+        # a source that is itself a queued destination reads what it will
+        # hold: the queue applies every read before any write
+        if self.arenas:
+            self._pending[dst] = self._pending.get(src, src)
+
+    def _fetch_pages(self, pages: List[int]) -> List[List[np.ndarray]]:
+        """Host copies of ``pages``, one list of per-arena blobs a page, in
+        one device read; counted in ``parked_bytes``."""
+        if not pages or not self.arenas:
+            return [[None] * self.num_leaves for _ in pages]
+        self.flush()
+        got = [np.asarray(x) for x in _take_pages(self.arenas, np.asarray(pages, np.int32))]
+        self.parked_bytes += sum(int(x.nbytes) for x in got)
+        out = []
+        for k in range(len(pages)):
+            it = iter(got)
+            out.append([None if a is None else next(it)[k] for a in self._arenas])
+        return out
 
     # ------------------------------------------------------------------ #
     # allocation
@@ -408,6 +565,7 @@ class PagedKVCache:
                 self._decref(p)
         self.seq_len.pop(rid, None)
         self._state.pop(rid, None)
+        self._step_writes.pop(rid, None)
         self._share_info.pop(rid, None)
         self._hit_rids.discard(rid)
 
@@ -424,9 +582,7 @@ class PagedKVCache:
         # recycled pages may hold a dead sequence's KV; zeroing keeps every
         # gathered view bit-identical to the dense reference cache
         self.zero_writes += 1
-        for a in self._arenas:
-            if a is not None:
-                a[page] = 0
+        self._queue_copy(page, self.zero_page)
 
     # ------------------------------------------------------------------ #
     # prefix index
@@ -483,9 +639,7 @@ class PagedKVCache:
                     f"copy-on-write for {rid!r} page slot {j}: no free pages"
                 )
             new = got[0]
-            for a in self._arenas:
-                if a is not None:
-                    a[new] = a[page]
+            self._queue_copy(new, page)
             self._ref[new] = 1
             self._ref[page] -= 1
             pt[j] = new
@@ -506,15 +660,20 @@ class PagedKVCache:
         return self.write_span(rid, cache, start, length)
 
     def write_span(self, rid: str, cache, start: int, end: int) -> int:
-        """Write positions ``[start, end)`` from a dense cache into pages;
-        state leaves are replaced wholesale.  Grows the page table to cover
-        ``end`` (unzeroed — every grown page is covered by this write plus
-        the explicit tail zero), raising ``PagesExhausted`` when it can't.
-        Returns the bytes read back from the device: the whole of every
-        leaf that is a device array."""
+        """Write positions ``[start, end)`` from a dense cache into pages on
+        the device; state leaves are replaced wholesale on the host.  Grows
+        the page table to cover ``end`` (unzeroed — every grown page is
+        covered by this write plus the explicit tail zero), raising
+        ``PagesExhausted`` when it can't.  Returns the bytes read back from
+        the device: the state leaves that are device arrays (the paged
+        leaves never leave it)."""
         leaves, _ = _flatten(cache)
         assert len(leaves) == self.num_leaves
-        bytes_d2h = sum(int(x.nbytes) for x in leaves if isinstance(x, jax.Array))
+        bytes_d2h = sum(
+            int(x.nbytes)
+            for x, p in zip(leaves, self.paged)
+            if not p and isinstance(x, jax.Array)
+        )
         with obs.span("kv.write", rid=rid, bytes_d2h=bytes_d2h):
             self._write_span(rid, leaves, start, end)
         return bytes_d2h
@@ -527,44 +686,82 @@ class PagedKVCache:
         old_len = self.seq_len[rid]
         j0, j1 = start // ps, (max(end, start + 1) - 1) // ps
         pages = [self._writable_page(rid, j) for j in range(j0, j1 + 1)]
+        paged = [leaf for leaf, p in zip(leaves, self.paged) if p]
+        if paged:
+            # one program per cache width: the page list is padded to the
+            # pages that width spans
+            n = max(len(pages), math.ceil(paged[0].shape[2] / ps))
+            table = np.full(n, self.zero_page + 1, np.int32)
+            table[: len(pages)] = pages
+            self.flush()
+            self.arenas = _write_span_pages(
+                self.arenas, paged, table, np.int32(j0), np.int32(start),
+                np.int32(end), np.bool_(end >= old_len),
+            )
         for i, leaf in enumerate(leaves):
-            arr = np.asarray(leaf)
-            if self.paged[i]:
-                for j, page in zip(range(j0, j1 + 1), pages):
-                    lo, hi = max(j * ps, start), min((j + 1) * ps, end)
-                    if hi <= lo:
-                        continue
-                    self._arenas[i][page, :, lo - j * ps : hi - j * ps] = (
-                        arr[:, 0, lo:hi]
-                    )
-                # the frontier page may be fresh (allocated unzeroed by the
-                # ensure_capacity above): zero the not-yet-written tail so
-                # gathered views stay bit-identical to the dense reference
-                if end >= old_len and end % ps:
-                    self._arenas[i][pages[-1], :, end % ps :] = 0
-            else:
-                self._state[rid][i] = arr.copy()
+            if not self.paged[i]:
+                self._state[rid][i] = np.array(leaf)
         self.seq_len[rid] = max(old_len, end)
         if self.prefix_sharing:
             self._register(rid)
+
+    def prepare_write(self, rid: str, position: int) -> int:
+        """Resolve the page that takes ``rid``'s write at ``position``:
+        grow the table (zeroing the new page), void overwritten prompt
+        digests, copy a shared page first (COW).  Raises ``PagesExhausted``
+        before anything is written."""
+        if not self.ensure_capacity(rid, position + 1):
+            raise PagesExhausted(f"no pages to append to {rid!r}")
+        self._mark_overwritten(rid, position, position + 1)
+        return self._writable_page(rid, position // self.page_size)
 
     def append_token(self, rid: str, slices, position: int) -> None:
         """Write one decode step's output for one lane: ``slices`` is a
         flat leaf list — paged leaves ``(repeat, ...feat)`` (the KV written
         at ``position``, batch/seq axes squeezed), state leaves
-        ``(repeat, 1, ...)`` replace the stored state wholesale."""
-        if not self.ensure_capacity(rid, position + 1):
-            raise PagesExhausted(f"no pages to append to {rid!r}")
-        self._mark_overwritten(rid, position, position + 1)
-        page = self._writable_page(rid, position // self.page_size)
-        off = position % self.page_size
+        ``(repeat, 1, ...)`` replace the stored state wholesale.
+
+        With ``slices=None`` the page is resolved (``prepare_write``) and
+        the write is left to the next decode step's program, which reads
+        the target from ``step_pages``; ``commit_token`` completes it."""
+        page = self.prepare_write(rid, position)
+        if slices is None:
+            self._step_writes[rid] = (page, position)
+            return
+        self.flush()
+        paged = [np.asarray(x)[None] for x, p in zip(slices, self.paged) if p]
+        if paged:
+            self.arenas = _write_slots(
+                self.arenas, np.asarray([page], np.int32),
+                np.asarray([position], np.int32), paged,
+            )
         for i, leaf in enumerate(slices):
-            arr = np.asarray(leaf)
-            if self.paged[i]:
-                self._arenas[i][page, :, off] = arr
-            else:
-                self._state[rid][i] = arr.copy()
+            if not self.paged[i]:
+                self._state[rid][i] = np.array(leaf)
         self.seq_len[rid] = max(self.seq_len[rid], position + 1)
+
+    def step_pages(self, rids: List[Optional[str]]) -> np.ndarray:
+        """int32 ``(B,)``: the page each lane's decode write goes to, as
+        ``append_token(rid, None, pos)`` resolved it; out of range (the
+        write is dropped) for empty lanes and lanes with no write."""
+        out = np.full(len(rids), self.zero_page + 1, np.int32)
+        for b, rid in enumerate(rids):
+            if rid in self._step_writes:
+                out[b] = self._step_writes[rid][0]
+        return out
+
+    def commit_token(self, rid: str, states) -> None:
+        """Complete the decode write ``append_token(rid, None, pos)``
+        resolved, once the step has written its page: ``seq_len`` grows
+        and ``states`` (the state leaves, in leaf order) are stored."""
+        rec = self._step_writes.pop(rid, None)
+        if rec is None:
+            return
+        it = iter(states)
+        for i, p in enumerate(self.paged):
+            if not p:
+                self._state[rid][i] = np.array(next(it))
+        self.seq_len[rid] = max(self.seq_len[rid], rec[1] + 1)
 
     # ------------------------------------------------------------------ #
     # reads
@@ -573,62 +770,59 @@ class PagedKVCache:
         pt = [] if rid is None else self.page_table[rid]
         return list(pt) + [self.zero_page] * (self.view_pages - len(pt))
 
-    def gather(self, rids: List[Optional[str]]):
-        """Materialize the dense batch view for a list of lanes (None =
-        empty lane, all zeros).  Leaves come back shaped like
-        ``init_cache(cfg, B, view_pages * page_size)``."""
-        with obs.span("kv.gather") as sp:
-            B = len(rids)
-            tables = np.asarray([self._full_table(r) for r in rids], np.int64)
-            leaves = []
-            for i in range(self.num_leaves):
-                if self.paged[i]:
-                    a = self._arenas[i][tables]  # (B, VP, repeat, ps, ...feat)
-                    a = np.moveaxis(a, 2, 0)  # (repeat, B, VP, ps, ...)
-                    leaves.append(
-                        a.reshape(a.shape[:2] + (-1,) + a.shape[4:])
-                    )
-                else:
-                    zero = np.zeros(self._state_shape[i], self._dtypes[i])
-                    leaves.append(
-                        np.concatenate(
-                            [
-                                (zero if r is None else self._state[r][i])
-                                for r in rids
-                            ],
-                            axis=1,
-                        )
-                        if B
-                        else zero
-                    )
-            sp.set_metadata(bytes=sum(int(x.nbytes) for x in leaves))
+    def gather(self, rids: List[Optional[str]]) -> np.ndarray:
+        """The int32 page table ``(B, view_pages)`` of a list of lanes
+        (None = empty lane), padded with the zero page: what a decode step
+        gathers its batch view through (``gather_view``)."""
+        return np.asarray([self._full_table(r) for r in rids], np.int32)
+
+    def lane_states(self, rids: List[Optional[str]]) -> List[np.ndarray]:
+        """The state leaves of a list of lanes, each ``(repeat, B, ...)``
+        (zeros for an empty lane), in leaf order."""
+        out = []
+        for i in range(self.num_leaves):
+            if self.paged[i]:
+                continue
+            zero = np.zeros(self._state_shape[i], self._dtypes[i])
+            out.append(
+                np.concatenate(
+                    [zero if r is None else self._state[r][i] for r in rids], axis=1
+                )
+                if rids
+                else zero
+            )
+        return out
+
+    def view(self, rids: List[Optional[str]]):
+        """The dense batch view of a list of lanes (None = empty lane, all
+        zeros), built on the device as a decode step builds it.  Leaves
+        come back shaped like ``init_cache(cfg, B, view_pages *
+        page_size)``."""
+        self.flush()
+        paged = iter(_view(self.arenas, self.gather(rids)) if self.arenas else [])
+        states = iter(self.lane_states(rids))
+        leaves = [next(paged) if p else next(states) for p in self.paged]
         return jax.tree_util.tree_unflatten(self.treedef, leaves)
 
     def read_dense(self, rid: str, s_max: Optional[int] = None):
         """Dense single-sequence cache for ``rid`` — shaped like
         ``init_cache(cfg, 1, s_max)`` with every written position equal to
-        the page contents bit-for-bit (the property-test contract)."""
+        the page contents bit-for-bit (the property-test contract) and zero
+        beyond.  Paged leaves are gathered on the device and stay there."""
         length = self.seq_len[rid]
         s_max = length if s_max is None else s_max
         if s_max < length:
             raise ValueError("s_max shorter than written length")
-        ps = self.page_size
-        leaves = []
-        for i in range(self.num_leaves):
-            if self.paged[i]:
-                a = self._arenas[i]
-                repeat, feat = a.shape[1], a.shape[3:]
-                out = np.zeros(
-                    (repeat, 1, s_max) + feat, self._dtypes[i]
-                )
-                for j, page in enumerate(self.page_table[rid]):
-                    w = min(ps, length - j * ps)
-                    if w <= 0:
-                        break
-                    out[:, 0, j * ps : j * ps + w] = a[page, :, :w]
-                leaves.append(out)
-            else:
-                leaves.append(self._state[rid][i].copy())
+        n = max(1, self.pages_needed(s_max))
+        table = (list(self.page_table[rid]) + [self.zero_page] * n)[:n]
+        self.flush()
+        paged = iter(_read_pages(
+            self.arenas, np.asarray(table, np.int32), np.int32(length), s_max=s_max
+        ) if self.arenas else [])
+        leaves = [
+            next(paged) if p else self._state[rid][i].copy()
+            for i, p in enumerate(self.paged)
+        ]
         return jax.tree_util.tree_unflatten(self.treedef, leaves)
 
     # ------------------------------------------------------------------ #
@@ -640,18 +834,14 @@ class PagedKVCache:
         reference (parking copies and frees nothing for them — the prefix
         span survives for siblings and for our own resume)."""
         pt = self.page_table.pop(rid)
+        self._step_writes.pop(rid, None)
+        private = iter(self._fetch_pages([p for p in pt if self._ref[p] == 1]))
         slots = []
         for p in pt:
             if self._ref[p] > 1:
                 slots.append({"page": p, "blobs": None})
             else:
-                slots.append({
-                    "page": None,
-                    "blobs": [
-                        None if a is None else a[p].copy()
-                        for a in self._arenas
-                    ],
-                })
+                slots.append({"page": None, "blobs": next(private)})
                 self._decref(p)
         self._parked[rid] = {
             "slots": slots,
@@ -679,15 +869,33 @@ class PagedKVCache:
                 continue
             p = next(it)
             self._ref[p] = 1
-            for a, blob in zip(self._arenas, slot["blobs"]):
-                if a is not None:
-                    a[p] = blob
             table.append(p)
+        self._put_blobs(pages, [park["slots"][j]["blobs"] for j in private])
         self.page_table[rid] = table
         self.seq_len[rid] = park["seq_len"]
         self._state[rid] = park["state"]
         del self._parked[rid]
         return True
+
+    def _put_blobs(self, pages: List[int], blobs) -> None:
+        """Write host page copies back into ``pages`` in one device call
+        (padded to a bucket of page counts); counted in ``parked_bytes``."""
+        if not pages or not self.arenas:
+            return
+        n = _bucket(len(pages))
+        table = np.full(n, self.zero_page + 1, np.int32)
+        table[: len(pages)] = pages
+        blocks = []
+        for k, a in enumerate(self._arenas):
+            if a is None:
+                continue
+            blk = np.zeros((n,) + a.shape[1:], self._dtypes[k])
+            for j, b in enumerate(blobs):
+                blk[j] = b[k]
+            self.parked_bytes += int(blk[: len(pages)].nbytes)
+            blocks.append(blk)
+        self.flush()
+        self.arenas = _put_pages(self.arenas, table, blocks)
 
     def is_parked(self, rid: str) -> bool:
         return rid in self._parked
@@ -704,14 +912,12 @@ class PagedKVCache:
         Lossless — resume re-allocates them like any private page.  Returns
         the number of references released (the terminal-pressure escape
         valve: without it, parked siblings could pin the arena)."""
+        slots = [s for s in self._parked[rid]["slots"] if s["page"] is not None]
+        blobs = self._fetch_pages([s["page"] for s in slots])
         released = 0
-        for slot in self._parked[rid]["slots"]:
+        for slot, blob in zip(slots, blobs):
             page = slot["page"]
-            if page is None:
-                continue
-            slot["blobs"] = [
-                None if a is None else a[page].copy() for a in self._arenas
-            ]
+            slot["blobs"] = blob
             slot["page"] = None
             self._decref(page)
             released += 1

@@ -58,7 +58,9 @@ from .. import obs
 from ..models.config import ModelConfig
 from ..models.transformer import decode_step, init_cache, prefill
 from ..models.transformer import prefill_chunk as _prefill_chunk_fn
-from .paged_cache import PagedKVCache, PagesExhausted
+from .paged_cache import (
+    PagedKVCache, PagesExhausted, copy_pages, gather_view, write_slots,
+)
 
 __all__ = ["Request", "ContinuousBatchingScheduler"]
 
@@ -102,11 +104,18 @@ class Request:
         )
 
 
-def _make_lane_step(cfg: ModelConfig, paged_mask):
-    """Jitted per-step decoder: vmap of the single-sequence decode over
-    lanes with per-lane (cache view, position, key, temperature).  Returns
-    (next_token (B,), logits (B, V) f32, written-slice pytree).  The program
-    is named ``serve_lane_step`` (``jit_serve_lane_step`` in a trace)."""
+def _make_lane_step(cfg: ModelConfig, kv: PagedKVCache):
+    """Jitted per-step decoder over the device arenas of ``kv``: applies the
+    queued page copies, gathers every lane's dense cache view through the
+    int32 page table inside the trace, runs the vmap of the single-sequence
+    decode over lanes with per-lane (view, position, key, temperature), and
+    writes each lane's new KV slice into the page resolved for it.  Takes
+    ``(params, tokens (B, 1), arenas, table (B, VP), states, positions (B,),
+    keys, temperatures, write pages (B,), copy src, copy dst)``; returns
+    ``(next_token (B,), logits (B, V) f32, (arenas, states))`` with the
+    arenas donated and updated in place.  The program is named
+    ``serve_lane_step`` (``jit_serve_lane_step`` in a trace)."""
+    paged, treedef = list(kv.paged), kv.treedef
 
     def one(params, tok, cache_b, pos, rng, temp):
         cache1 = jax.tree.map(lambda a: a[:, None], cache_b)  # re-add B=1
@@ -117,20 +126,29 @@ def _make_lane_step(cfg: ModelConfig, paged_mask):
             rng, row / jnp.maximum(temp, 1e-6)
         )
         nxt = jnp.where(temp > 0, sampled, greedy).astype(jnp.int32)
-        sl = jax.tree.map(
-            lambda a, m: (
-                jax.lax.dynamic_slice_in_dim(a, pos, 1, axis=2)[:, 0, 0]
-                if m
-                else a
-            ),
-            nc,
-            paged_mask,
-        )
+        sl = [
+            jax.lax.dynamic_slice_in_dim(a, pos, 1, axis=2)[:, 0, 0] if m else a
+            for a, m in zip(jax.tree_util.tree_leaves(nc), paged)
+        ]
         return nxt[0], row[0], sl
 
     lanes = jax.vmap(one, in_axes=(None, 0, 1, 0, 0, 0))
-    lanes.__name__ = lanes.__qualname__ = "serve_lane_step"
-    return jax.jit(lanes)
+
+    def serve_lane_step(
+        params, toks, arenas, table, states, pos, keys, temps, pages, src, dst
+    ):
+        arenas = copy_pages(arenas, src, dst)
+        views, st = iter([gather_view(a, table) for a in arenas]), iter(states)
+        cache = jax.tree_util.tree_unflatten(
+            treedef, [next(views) if m else next(st) for m in paged]
+        )
+        nxt, logits, sl = lanes(params, toks, cache, pos, keys, temps)
+        arenas = write_slots(
+            arenas, pages, pos, [x for x, m in zip(sl, paged) if m]
+        )
+        return nxt, logits, (arenas, [x for x, m in zip(sl, paged) if not m])
+
+    return jax.jit(serve_lane_step, donate_argnums=2)
 
 
 class ContinuousBatchingScheduler:
@@ -219,7 +237,7 @@ class ContinuousBatchingScheduler:
         )
         # fixed dense width for chunk compute: one retrace per chunk length
         self._prefill_width = self.kv.view_pages * int(page_size)
-        self._lane_step = _make_lane_step(cfg, self.kv.paged_mask)
+        self._lane_step = _make_lane_step(cfg, self.kv)
 
         self.queue: List[Request] = []  # kept in arrival order
         self.lanes: List[Optional[Request]] = [None] * self.max_batch
@@ -242,6 +260,7 @@ class ContinuousBatchingScheduler:
             # host<->device bytes of the KV path (see repro.obs)
             "kv_h2d_bytes": 0,
             "kv_d2h_bytes": 0,
+            "kv_parked_bytes": 0,
             "decode_steps_with_view": 0,
         }
 
@@ -445,8 +464,10 @@ class ContinuousBatchingScheduler:
                 return
             req = self.queue[qi]
             if req.state == PREEMPTED:
+                moved = self.kv.parked_bytes
                 if not self.kv.resume(req.rid):
                     return  # head-of-line blocking on pages: deterministic
+                self._count_parked(moved, "kv_h2d_bytes")
                 self.stats["resumes"] += 1
                 ev["resumed"].append(req.rid)
             elif self.prefix_sharing or self.chunked_prefill:
@@ -545,8 +566,13 @@ class ContinuousBatchingScheduler:
                 raise PagesExhausted(f"admission reserve lost for {req.rid!r}")
             self._evict(req, ev)
             return
+        # gathered on the device; only host leaves (none: the cache is
+        # fully paged here) would cross
         dense = self.kv.read_dense(req.rid, s_max=self._prefill_width)
-        h2d = sum(int(x.nbytes) for x in jax.tree.leaves(dense))
+        h2d = sum(
+            int(x.nbytes) for x in jax.tree.leaves(dense)
+            if not isinstance(x, jax.Array)
+        )
         with obs.span("kv.upload", bytes_h2d=h2d):
             dense = jax.block_until_ready(jax.device_put(dense))
         self.stats["kv_h2d_bytes"] += h2d
@@ -614,13 +640,24 @@ class ContinuousBatchingScheduler:
         if not parked:
             return False
         victim = max(parked, key=lambda r: (r.arrival, r.rid))
+        moved = self.kv.parked_bytes
         self.kv.release_parked_shared(victim.rid)
+        self._count_parked(moved, "kv_d2h_bytes")
         self.stats["shared_releases"] += 1
         return True
 
+    def _count_parked(self, before: int, direction: str) -> None:
+        """Count the KV bytes the cache parked or restored since its
+        ``parked_bytes`` read ``before``: the only KV that crosses."""
+        moved = self.kv.parked_bytes - before
+        self.stats["kv_parked_bytes"] += moved
+        self.stats[direction] += moved
+
     def _evict(self, req: Request, ev: dict) -> None:
         lane = self.lanes.index(req)
+        moved = self.kv.parked_bytes
         self.kv.evict(req.rid)
+        self._count_parked(moved, "kv_d2h_bytes")
         self.lanes[lane] = None
         req.state = PREEMPTED
         self.stats["evictions"] += 1
@@ -708,95 +745,93 @@ class ContinuousBatchingScheduler:
         self.transcript.append(ev)
         return ev
 
+    def _reserve_writes(self, active: List[int], ev: dict) -> dict:
+        """Resolve the page every decoding lane writes this step (growth,
+        COW) before the device runs.  ``PagesExhausted`` evicts per policy
+        (youngest other lane first), then tries the shared-page escape
+        valve, then parks the lane itself losslessly: a parked lane takes
+        no part in the step, so nothing computed is lost and its rng is
+        never split.  Returns ``{lane: request}`` of the lanes that
+        decode."""
+        for i in active:
+            req = self.lanes[i]
+            if req is None:
+                continue  # parked by an earlier lane's page pressure
+            pos = req.prompt_len + len(req.tokens) - 1
+            while True:
+                try:
+                    self.kv.append_token(req.rid, None, pos)
+                    break
+                except PagesExhausted:
+                    others = [
+                        r for r in self.lanes if r is not None and r is not req
+                    ]
+                    if others:
+                        self._evict(max(others, key=lambda r: (r.arrival, r.rid)), ev)
+                        continue
+                    if self._release_parked_shared_one():
+                        continue
+                    self._evict(req, ev)
+                    break
+        return {i: self.lanes[i] for i in active if self.lanes[i] is not None}
+
     def _decode_once(self, active: List[int], ev: dict) -> None:
         B = self.max_batch
+        with obs.span("kv.gather") as sp:
+            lane_reqs = self._reserve_writes(active, ev)
+            if not lane_reqs:
+                return  # the only lane parked itself
+            rids = [lane_reqs[i].rid if i in lane_reqs else None for i in range(B)]
+            table = self.kv.gather(rids)
+            pages = self.kv.step_pages(rids)
+            # a lane queues at most a zeroed page and a COW copy a step
+            src, dst = self.kv.take_copies(2 * B)
+            states = self.kv.lane_states(rids)
+            sp.set_metadata(bytes=int(table.nbytes))
         toks = np.zeros((B, 1), np.int32)
         pos = np.zeros((B,), np.int32)
         temps = np.zeros((B,), np.float32)
         keys = []
         zero_key = np.zeros_like(np.asarray(jax.random.PRNGKey(0)))
-        active_set = set(active)
-        lane_reqs = {}
-        rng_before = {}
         for i in range(B):
-            req = self.lanes[i] if i in active_set else None
+            req = lane_reqs.get(i)
             if req is None:
                 keys.append(zero_key)
                 continue
-            lane_reqs[i] = req
             toks[i, 0] = req.tokens[-1]
             pos[i] = req.prompt_len + len(req.tokens) - 1
             temps[i] = req.temperature
             # mirror ServeEngine.generate: split every step, sample with sub
-            rng_before[i] = req.rng  # rewound if this step's write is lost
             req.rng, sub = jax.random.split(req.rng)
             keys.append(np.asarray(sub))
-        view = self.kv.gather(
-            [lane_reqs[i].rid if i in lane_reqs else None for i in range(B)]
-        )
-        # the step cannot start before its view is on the device, so waiting
-        # for the upload here adds no wait; it only names the transfer
-        h2d = sum(int(x.nbytes) for x in jax.tree.leaves(view))
+        host = [toks, table, pos, np.stack(keys), temps, pages, src, dst] + states
+        # the step cannot start before its arguments are on the device, so
+        # waiting for the upload here adds no wait; it only names the transfer
+        h2d = sum(int(x.nbytes) for x in host)
         with obs.span("kv.upload", bytes_h2d=h2d):
-            view = jax.block_until_ready(jax.device_put(view))
+            dev = jax.block_until_ready(jax.device_put(host))
         self.stats["kv_h2d_bytes"] += h2d
         self.stats["decode_steps_with_view"] += 1
+        d_toks, d_table, d_pos, d_keys, d_temps, d_pages, d_src, d_dst = dev[:8]
         with obs.span("serve.lane_step"):
-            nxt, logits, slices = jax.block_until_ready(
+            nxt, logits, (arenas, new_states) = jax.block_until_ready(
                 self._lane_step(
-                    self.params,
-                    jnp.asarray(toks),
-                    view,
-                    jnp.asarray(pos),
-                    jnp.asarray(np.stack(keys)),
-                    jnp.asarray(temps),
+                    self.params, d_toks, self.kv.arenas, d_table, dev[8:],
+                    d_pos, d_keys, d_temps, d_pages, d_src, d_dst,
                 )
             )
-        flat = jax.tree.leaves(slices)
-        kv_bytes = sum(int(x.nbytes) for x in flat)
+        self.kv.arenas = arenas
+        kv_bytes = sum(int(x.nbytes) for x in new_states)
         d2h = int(nxt.nbytes) + int(logits.nbytes) + kv_bytes
         with obs.span("serve.readback", bytes_d2h=d2h, kv_bytes=kv_bytes):
             nxt = np.asarray(nxt)
             logits = np.asarray(logits)
-            flat = [np.asarray(leaf) for leaf in flat]
+            new_states = [np.asarray(x) for x in new_states]
         self.stats["kv_d2h_bytes"] += kv_bytes
         now = self.clock()
-        with obs.span("kv.append", lanes=len(active)):
-            for i in active:
-                req = lane_reqs[i]
-                if self.lanes[i] is not req:
-                    # parked by an earlier lane's page pressure before its own
-                    # append: this step's write is lost, so rewind the rng split
-                    # — the redone step after resume samples identically
-                    req.rng = rng_before[i]
-                    continue
-                slices_i = [leaf[i] for leaf in flat]
-                while True:
-                    try:
-                        self.kv.append_token(req.rid, slices_i, int(pos[i]))
-                        break
-                    except PagesExhausted:
-                        # COW or growth needed a page mid-append: evict per
-                        # policy (youngest other lane first), then the shared-
-                        # page escape valve, then park this lane losslessly
-                        others = [
-                            r
-                            for r in self.lanes
-                            if r is not None and r is not req
-                        ]
-                        if others:
-                            victim = max(
-                                others, key=lambda r: (r.arrival, r.rid)
-                            )
-                            self._evict(victim, ev)
-                            continue
-                        if self._release_parked_shared_one():
-                            continue
-                        self._evict(req, ev)
-                        req.rng = rng_before[i]
-                        break
-                if self.lanes[i] is not req:
-                    continue  # parked itself above
+        with obs.span("kv.append", lanes=len(lane_reqs)):
+            for i, req in lane_reqs.items():
+                self.kv.commit_token(req.rid, [x[i] for x in new_states])
                 req.tokens.append(int(nxt[i]))
                 if self.record_logits:
                     req.logits.append(logits[i])

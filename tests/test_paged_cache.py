@@ -246,7 +246,7 @@ def test_gather_pads_with_zero_page(gqa_cfg):
     assert kv.alloc_seq("a", 3)
     cache, _ = _random_prefill_cache(gqa_cfg, 3, rng)
     kv.write_prefill("a", cache, 3)
-    view = kv.gather(["a", None])
+    view = kv.view(["a", None])
     leaves, _ = jax.tree_util.tree_flatten(view)
     ref_leaves, _ = jax.tree_util.tree_flatten(kv.read_dense("a", s_max=16))
     for i, (v, r) in enumerate(zip(leaves, ref_leaves)):
@@ -314,7 +314,7 @@ def test_prefill_path_does_not_double_zero(gqa_cfg):
     cache, _ = _random_prefill_cache(gqa_cfg, 10, rng)
     kv.write_prefill("a", cache, 10)
     assert kv.zero_writes == 0
-    view = kv.gather(["a"])
+    view = kv.view(["a"])
     leaves, _ = jax.tree_util.tree_flatten(view)
     for i, v in enumerate(leaves):
         if kv.paged[i]:

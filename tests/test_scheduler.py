@@ -496,9 +496,11 @@ def test_sharing_and_chunking_compose_under_page_pressure(engine):
 
 
 def test_pages_exhausted_mid_decode_evicts_and_resumes_lossless(engine, monkeypatch):
-    """A typed PagesExhausted raised mid-append (the COW/growth path) must
-    evict per policy and keep every sequence lossless — including the
-    victim whose step was dropped before its append (rng rewind)."""
+    """A typed PagesExhausted raised while a decode step's writes are
+    resolved (growth/COW, before the device step) must evict per policy and
+    keep every sequence lossless: the evicted lane takes no part in the
+    step, and a lane that parks itself never splits its rng, so its redone
+    step after resume samples identically."""
     from repro.serve.paged_cache import PagesExhausted
 
     cfg = engine.cfg
@@ -511,25 +513,31 @@ def test_pages_exhausted_mid_decode_evicts_and_resumes_lossless(engine, monkeypa
             p, max_new_tokens=6, temperature=0.7,
             rng=jax.random.PRNGKey(500 + i), rid=f"x{i}", arrival=float(i),
         )
-    real = sched.kv.append_token
+    real = sched.kv.prepare_write
     fired = {}
-    def flaky(rid, slices, position):
+    def flaky(rid, position):
         if rid == "x0" and position >= 7 and "x0" not in fired:
-            fired["x0"] = True
+            # raised before the step: nothing of this step has run yet
+            fired["x0"] = sched.stats["decode_steps_with_view"]
             raise PagesExhausted("forced mid-decode exhaustion")
-        return real(rid, slices, position)
-    monkeypatch.setattr(sched.kv, "append_token", flaky)
+        return real(rid, position)
+    monkeypatch.setattr(sched.kv, "prepare_write", flaky)
     results = sched.run()
     assert fired, "the forced exhaustion never triggered"
     assert sched.stats["evictions"] >= 1
+    # x1 (youngest) was evicted before the step, x0 decoded in it
+    (ev,) = [e for e in sched.transcript if e["evicted"]][:1]
+    assert ev["evicted"] == ["x1"] and "x1" in ev["running"]
+    assert sched.stats["kv_parked_bytes"] > 0
     for i, p in enumerate(prompts):
         ref = _reference(
             engine, p, 6, temperature=0.7, rng=jax.random.PRNGKey(500 + i)
         )
         np.testing.assert_array_equal(results[f"x{i}"]["tokens"], ref)
 
-    # single lane: nothing to evict -> the lane parks ITSELF, rewinds its
-    # rng split, and redoes the step after resume with identical sampling
+    # single lane: nothing to evict -> the lane parks ITSELF before the
+    # step (its rng never split) and redoes the step after resume with
+    # identical sampling
     sched2 = engine.make_scheduler(
         page_size=4, max_batch=1, num_pages=8, clock=_fake_clock()
     )
@@ -537,20 +545,89 @@ def test_pages_exhausted_mid_decode_evicts_and_resumes_lossless(engine, monkeypa
         prompts[0], max_new_tokens=6, temperature=0.7,
         rng=jax.random.PRNGKey(500), rid="solo",
     )
-    real2 = sched2.kv.append_token
+    real2 = sched2.kv.prepare_write
     fired2 = {}
-    def flaky2(rid, slices, position):
+    def flaky2(rid, position):
         if position >= 7 and not fired2:
-            fired2["solo"] = True
+            fired2["solo"] = sched2.stats["decode_steps_with_view"]
             raise PagesExhausted("forced self-park")
-        return real2(rid, slices, position)
-    monkeypatch.setattr(sched2.kv, "append_token", flaky2)
+        return real2(rid, position)
+    monkeypatch.setattr(sched2.kv, "prepare_write", flaky2)
     results2 = sched2.run()
     assert fired2 and sched2.stats["evictions"] >= 1
+    # the step that parked its only lane dispatched nothing
+    n_decode = sum(1 for e in sched2.transcript if e["running"])
+    assert sched2.stats["decode_steps_with_view"] == n_decode - 1
     ref = _reference(
         engine, prompts[0], 6, temperature=0.7, rng=jax.random.PRNGKey(500)
     )
     np.testing.assert_array_equal(results2["solo"]["tokens"], ref)
+
+
+def _step_arg_bytes(sched):
+    """Host bytes a decode step puts on the device: the int32 page table,
+    tokens, positions, keys, temperatures, write pages and 2 x 2B queued
+    page copies (the model has no state leaves)."""
+    B, VP = sched.max_batch, sched.kv.view_pages
+    key = np.asarray(jax.random.PRNGKey(0)).nbytes
+    return 4 * B * VP + 4 * B * 4 + B * key + 2 * 4 * 2 * B
+
+
+def test_plain_decode_moves_no_kv(engine):
+    """With the arenas on the device, a decode step uploads its page table
+    and arguments and reads back no KV; prefill writes read none back."""
+    cfg = engine.cfg
+    prompts = _prompts(cfg, [3, 7, 5], seed=13)
+    reqs = [
+        {"prompt": p, "max_new_tokens": 6, "rid": f"n{i}"}
+        for i, p in enumerate(prompts)
+    ]
+    results, sched = engine.serve(reqs, page_size=4, max_batch=4)
+    st = sched.stats
+    assert st["evictions"] == 0 and st["decode_steps_with_view"] > 0
+    assert st["kv_h2d_bytes"] <= st["decode_steps_with_view"] * _step_arg_bytes(sched)
+    assert st["kv_d2h_bytes"] == 0 and st["kv_parked_bytes"] == 0
+    for i, p in enumerate(prompts):
+        np.testing.assert_array_equal(results[f"n{i}"]["tokens"], _reference(engine, p, 6))
+
+
+def test_forced_eviction_parks_kv_and_matches_generate(engine):
+    """Page pressure parks private pages to the host and back — the one
+    KV transfer left, counted in ``kv_parked_bytes`` — and sampled decode
+    through it still matches independent generate runs token-for-token."""
+    cfg = engine.cfg
+    prompts = _prompts(cfg, [6, 6, 6], seed=29)
+    reqs = [
+        {"prompt": p, "max_new_tokens": 8, "rid": f"k{i}", "temperature": 0.8,
+         "rng": jax.random.PRNGKey(40 + i)}
+        for i, p in enumerate(prompts)
+    ]
+    results, sched = engine.serve(reqs, page_size=4, max_batch=3, num_pages=9)
+    st = sched.stats
+    assert st["evictions"] > 0 and st["resumes"] > 0
+    page = sum(int(np.prod(a.shape[1:])) * a.dtype.itemsize for a in sched.kv.arenas)
+    assert st["kv_parked_bytes"] > 0 and st["kv_parked_bytes"] % page == 0
+    assert st["kv_d2h_bytes"] + st["kv_h2d_bytes"] >= st["kv_parked_bytes"]
+    for i, p in enumerate(prompts):
+        ref = _reference(engine, p, 8, temperature=0.8, rng=jax.random.PRNGKey(40 + i))
+        np.testing.assert_array_equal(results[f"k{i}"]["tokens"], ref)
+
+
+def test_zero_page_stays_zero_with_inactive_lanes(engine):
+    """Empty lanes' step writes are dropped, never aimed at the zero page
+    that pads every table: it reads all zeros after every step."""
+    cfg = engine.cfg
+    sched = engine.make_scheduler(page_size=4, max_batch=4)
+    for i, p in enumerate(_prompts(cfg, [5, 2], seed=3)):
+        sched.submit(p, max_new_tokens=7 - 3 * i, rid=f"z{i}")
+    saw_empty = False
+    while sched.pending():
+        sched.step()
+        saw_empty |= any(r is None for r in sched.lanes)
+        for a in sched.kv.arenas:
+            assert not np.any(np.asarray(a[sched.kv.zero_page]))
+    assert saw_empty
+    assert sched.kv.arenas, "the model must have paged leaves"
 
 
 def test_sharing_and_chunking_require_fully_paged_cache():
@@ -610,7 +687,8 @@ def test_serving_step_spans_nest_and_count_the_view_bytes(engine, tmp_path):
     sched.submit(_prompts(cfg, [4], seed=6)[0], max_new_tokens=2, rid="o2")
     sched.step()  # compile outside the trace
     before = dict(sched.stats)
-    view_bytes = sum(x.nbytes for x in jax.tree.leaves(sched.kv.gather([None] * 2)))
+    table_bytes = sched.kv.gather([None] * 2).nbytes
+    arg_bytes = _step_arg_bytes(sched)
     spans = _traced_host_spans(tmp_path, sched.run)
     names = [s[0] for s in spans]
 
@@ -632,9 +710,9 @@ def test_serving_step_spans_nest_and_count_the_view_bytes(engine, tmp_path):
     assert names.count("serve.step") == sched.stats["steps"] - before["steps"]
     assert [s[3]["rid"] for s in spans if s[0] in ("serve.prefill", "kv.write")] == ["o2"] * 2
 
-    # the upload is the gathered view, byte for byte
-    assert all(s[3]["bytes_h2d"] == view_bytes for s in spans if s[0] == "kv.upload")
-    assert all(s[3]["bytes"] == view_bytes for s in spans if s[0] == "kv.gather")
+    # the upload is the page table and the step's arguments, byte for byte
+    assert all(s[3]["bytes_h2d"] == arg_bytes for s in spans if s[0] == "kv.upload")
+    assert all(s[3]["bytes"] == table_bytes for s in spans if s[0] == "kv.gather")
     steps = [s[3] for s in spans if s[0] == "serve.step"]
     assert [s[3]["lanes"] for s in spans if s[0] == "serve.decode"] == [
         st["running"] for st in steps if st["running"]]
@@ -649,7 +727,9 @@ def test_serving_step_spans_nest_and_count_the_view_bytes(engine, tmp_path):
     assert grew("kv_d2h_bytes") == sum(
         s[3]["bytes_d2h"] for s in spans if s[0] == "kv.write") + sum(
         s[3]["kv_bytes"] for s in spans if s[0] == "serve.readback")
-    assert all(s[3]["bytes_d2h"] > s[3]["kv_bytes"] > 0 for s in spans if s[0] == "serve.readback")
+    # tokens and logits come back; KV stays on the device
+    assert all(s[3]["bytes_d2h"] > 0 == s[3]["kv_bytes"] for s in spans if s[0] == "serve.readback")
+    assert all(s[3]["bytes_d2h"] == 0 for s in spans if s[0] == "kv.write")
 
 
 def test_serving_spans_are_recorded_in_chunked_prefill(engine, tmp_path):
