@@ -5,6 +5,9 @@ Traffic parameters (``workloads/<traffic>.json``):
 * ``clients``: how many requests are in flight; each client sends its next
   request as soon as its last one finishes;
 * ``max_batch``, ``page_size``, ``max_len``: the scheduler's settings;
+* ``scheduler`` (optional): further keyword arguments for
+  ``ServeEngine.make_scheduler``, such as ``prefix_sharing`` or
+  ``chunked_prefill``;
 * ``prompt_lens``, ``output_lens``: one round of requests.  Every seed
   gets the same rounds; the seed pairs the lengths, orders the requests
   and draws the prompt tokens, so every seed does the same work;
@@ -13,6 +16,12 @@ Traffic parameters (``workloads/<traffic>.json``):
 * ``check_requests``: requests of the window compared with the reference
   (every one served in the window, where there are no more);
 * ``trace_seconds``: the longest window a traced run records.
+
+Everything that belongs to the model's architecture comes from the
+configuration's reference, through the contract that ``harness.py``
+documents: the program's config (``program_config``), the weights
+(``make_params``), the reference's logits (``scores``) and the operations a
+token needs (``work``).
 
 Set-up makes the weights on the device from ``--seed`` (the configuration's
 reference draws them for the program's parameter tree), builds
@@ -26,42 +35,14 @@ at once.  The window drives ``submit``/``step``; a token's time is when the
 from __future__ import annotations
 
 import dataclasses
-import importlib
 import time
 
 import numpy as np
 
 import harness
-import workcount
 from tracecalc import span
 
 CHECK_ROWS = 8  # requests the reference runs at once
-
-
-def model_config(cfg: dict):
-    """The program's ModelConfig for this configuration file, checked
-    against the file's published sizes."""
-    from repro.models.config import SableConfig
-
-    prog = cfg["program"]
-    mod_name, fn_name = prog["config"].split(":")
-    base = getattr(importlib.import_module(mod_name), fn_name)()
-    mcfg = dataclasses.replace(base, sable=SableConfig(**cfg["sable"]), **prog["replace"])
-    want = {
-        "d_model": cfg["hidden_size"], "n_heads": cfg["num_attention_heads"],
-        "n_kv_heads": cfg["num_key_value_heads"], "head_dim": cfg["head_dim"],
-        "d_ff": cfg["intermediate_size"], "vocab_size": cfg["vocab_size"],
-        "n_layers": cfg["num_hidden_layers"], "rope_theta": cfg["rope_theta"],
-        "norm_eps": cfg["rms_norm_eps"], "tie_embeddings": cfg["tie_word_embeddings"],
-        "ffn_type": "swiglu", "attn_type": "gqa", "param_dtype": cfg["precision"]["weights"],
-        "compute_dtype": cfg["precision"]["compute"],
-    }
-    got = {k: getattr(mcfg, k) for k in want}
-    bad = {k: (got[k], v) for k, v in want.items() if got[k] != v}
-    specs = {(s.mixer, s.ffn, s.cross_attn) for g in mcfg.groups for s in g.layers}
-    if bad or specs != {("gqa", "dense", False)} or mcfg.enc_groups:
-        raise ValueError(f"program config departs from {cfg['name']}: {bad}, layers {specs}")
-    return mcfg
 
 
 def make_requests(traffic: dict, vocab: int, seed: int) -> list:
@@ -93,20 +74,12 @@ def run(ctx: harness.Context) -> harness.Outcome:
     import jax
 
     from repro.models.transformer import init_params
-    from repro.models.layers import sable_patterns
     from repro.serve.engine import ServeEngine
 
     cell, traffic = ctx.cell, ctx.cell.traffic
     cfg = cell.config
     ref = cell.reference()
-    mcfg = model_config(cfg)
-
-    pats = ref.patterns(cfg)
-    prog_pats = sable_patterns(mcfg)
-    for name in ("in", "out"):
-        p = prog_pats[name]
-        if not (np.array_equal(p.rows, pats[name][0]) and np.array_equal(p.cols, pats[name][1])):
-            raise ValueError(f"the program's {name} pattern is not the configuration's")
+    mcfg = ref.program_config(cfg)
 
     t0 = time.perf_counter()
     shapes = jax.eval_shape(lambda: init_params(mcfg, jax.random.PRNGKey(0)))
@@ -118,7 +91,8 @@ def run(ctx: harness.Context) -> harness.Outcome:
     t0 = time.perf_counter()
     engine = ServeEngine(mcfg, params, max_len=int(traffic["max_len"]))
     sched = engine.make_scheduler(page_size=int(traffic["page_size"]),
-                                  max_batch=int(traffic["max_batch"]))
+                                  max_batch=int(traffic["max_batch"]),
+                                  **traffic.get("scheduler", {}))
     if ctx.fault is not None:
         ctx.fault(sched)
     harness.log(f"engine {time.perf_counter() - t0:.3f} s, warmup_stats {engine.warmup_stats}, "
@@ -229,11 +203,10 @@ def run(ctx: harness.Context) -> harness.Outcome:
     gap, control_gap = _check(ref, params, cfg, chosen, batch, control=ctx.control)
     attempted = sum(1 for t in everyone
                     if t.submitted <= t_end and (not t.stamps or t.stamps[-1] > t_start))
-    n_in, n_out = len(pats["in"][0]), len(pats["out"][0])
     counters = dict(counts, window_s=window_s, max_batch=int(traffic["max_batch"]),
                     sched_steps=stats1["steps"] - stats0["steps"],
                     sched_decode_tokens=stats1["decode_tokens"] - stats0["decode_tokens"],
-                    flops=workcount.decoder_flops(cfg, n_in, n_out))
+                    flops=ref.work(cfg))
     if ctx.control:  # the control's gap is the one compared; the program's is kept
         counters["program_gap"] = gap
         gap = control_gap
@@ -305,7 +278,7 @@ def _check(ref, params, cfg, chosen, batch, control: bool = False):
                 f"{float(gaps[0].max()):.6f}, median {float(np.median(gaps[0])):.6f}, "
                 f"tokens the reference would not pick first {int((gaps[0] > 0).sum())}")
     if control:
-        harness.log(f"control: widest gap of the float8 control's first tokens "
+        harness.log(f"control: widest gap of the {cfg.get('control_dtype')} control's first tokens "
                     f"{float(gaps[1].max()):.6f}, median {float(np.median(gaps[1])):.6f}, "
                     f"differing first tokens {int((gaps[1] > 0).sum())}")
     return float(gaps[0].max()), (float(gaps[1].max()) if control else None)

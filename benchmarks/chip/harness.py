@@ -15,6 +15,27 @@ per-layer metric lives in a file of its own under this directory:
 * a per-layer metric is ``metrics/<metric>.py`` with ``read(run)``, which
   returns a number or None when it finds nothing to read.
 
+A served model's reference owns everything that belongs to its
+architecture; the serving driver (``drivers/closed_loop.py``) knows none of
+it and reaches the reference through four functions:
+
+* ``program_config(cfg) -> ModelConfig``: the program's config, built from
+  the file's ``program`` entry (``module:function`` plus ``replace``) and
+  checked against the file's published sizes; raises on any departure;
+* ``make_params(cfg, shapes, key)``: weights for every leaf of ``shapes``
+  (the program's parameter tree as ShapeDtypeStructs), drawn from ``key``
+  on the device;
+* ``scores(params, cfg, tokens, targets, control)``: the reference's best
+  logit, the logits of the ``targets`` rows and its argmax at every
+  position of ``tokens``; with ``control``, computed in the configuration's
+  lower precision;
+* ``work(cfg) -> (per_token, per_key, per_logits_row)``: the operations
+  that ``mfu.serve`` counts, from the configuration alone, with no padding
+  or tiles that the mathematics does not need.
+
+A new architecture then brings a configuration file, a reference that keeps
+this contract, and its traffic, limits and metric files, and edits none.
+
 A driver's ``run(ctx)`` returns a :class:`Outcome`.  The harness never
 imports the program under test; the drivers and references do.
 """

@@ -2,10 +2,10 @@
 over the chip's bf16 peak, in %.
 
 Every prompt token of an admission and every decoded token passes through
-all layers (``workcount.decoder_flops``: projections, the FFN's stored tiles
-only); attention counts the keys each token attends; the unembedding counts
-once per logits row (a prefill produces one).  The token counts are the
-client's, over the window."""
+all layers (the configuration's reference counts them in ``work(cfg)``:
+for granite the projections and the FFN's stored tiles only); attention
+counts the keys each token attends; the unembedding counts once per logits
+row (a prefill produces one).  The token counts are the client's, over the window."""
 
 
 def read(run):

@@ -1,14 +1,17 @@
 """Plain float32 reference for a decoder-only Llama-architecture model whose
-SwiGLU FFN matrices are SABLE block-sparse.
+SwiGLU FFN matrices are full, or SABLE block-sparse where the configuration
+file has a ``sable`` entry.
 
-It imports nothing of the program.  The mathematics follows the Llama
+The forward pass, the weights and the work count import nothing of the
+program; ``program_config`` alone reaches it, to build the program's
+configuration and check it against the file.  The mathematics follows the Llama
 description that Granite Code uses (arXiv:2405.04324; HF ``LlamaForCausalLM``):
 token embedding, then per layer ``x += Wo attn(rope(Wq n(x)), rope(Wk n(x)),
 Wv n(x))`` with causal grouped-query attention (query head ``h`` reads
 key/value head ``h // (H / Kv)``, scale ``1/sqrt(head_dim)``), rotary
 embedding on the half-split layout, RMSNorm with a weight, and ``x +=
 W2 (silu(W1 n(x)) * (W3 n(x)))``; a final RMSNorm and logits against the
-tied embedding.  Each FFN matrix keeps only the 128 x 128 tiles of its
+tied embedding.  With ``sable``, each FFN matrix keeps only the tiles of its
 block pattern; the pattern comes from a copy of the generator the
 configuration names (``random_pattern`` below), and a tile ``(r, c)``
 holds rows ``r*tm:(r+1)*tm`` and columns ``c*tk:(c+1)*tk`` of the
@@ -21,14 +24,21 @@ at float32 and ``highest`` matmul precision, so that it fits beside the
 weights; with ``control=True`` the operands of every weight matmul are rounded
 first to float8 (e4m3, one scale per tensor): the lower precision of the
 control runs.
+
+It keeps the serving driver's reference contract (``harness.py``):
+``program_config``, ``make_params``, ``scores`` and ``work``.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
+import importlib
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+import workcount
 
 FP8_MAX = 448.0
 
@@ -65,6 +75,61 @@ def patterns(cfg: dict) -> dict:
 
 
 # ---------------------------------------------------------------------- #
+# the program's configuration, and the work a token needs
+# ---------------------------------------------------------------------- #
+def program_config(cfg: dict):
+    """The program's ModelConfig for this configuration file, built from the
+    file's ``program`` entry (``module:function`` plus ``replace``), with the
+    file's ``sable`` or none, and checked against the file's published sizes
+    and, with ``sable``, against this reference's tile patterns.  Raises on
+    any departure."""
+    from repro.models.config import SableConfig
+
+    prog = cfg["program"]
+    mod_name, fn_name = prog["config"].split(":")
+    base = getattr(importlib.import_module(mod_name), fn_name)()
+    sable = SableConfig(**cfg["sable"]) if "sable" in cfg else None
+    mcfg = dataclasses.replace(base, sable=sable, **prog["replace"])
+    want = {
+        "d_model": cfg["hidden_size"], "n_heads": cfg["num_attention_heads"],
+        "n_kv_heads": cfg["num_key_value_heads"], "head_dim": cfg["head_dim"],
+        "d_ff": cfg["intermediate_size"], "vocab_size": cfg["vocab_size"],
+        "n_layers": cfg["num_hidden_layers"], "rope_theta": cfg["rope_theta"],
+        "norm_eps": cfg["rms_norm_eps"], "tie_embeddings": cfg["tie_word_embeddings"],
+        "ffn_type": "swiglu", "attn_type": "gqa", "param_dtype": cfg["precision"]["weights"],
+        "compute_dtype": cfg["precision"]["compute"],
+    }
+    got = {k: getattr(mcfg, k) for k in want}
+    bad = {k: (got[k], v) for k, v in want.items() if got[k] != v}
+    specs = {(s.mixer, s.ffn, s.cross_attn) for g in mcfg.groups for s in g.layers}
+    if bad or specs != {("gqa", "dense", False)} or mcfg.enc_groups:
+        raise ValueError(f"program config departs from {cfg['name']}: {bad}, layers {specs}")
+    if sable is not None:
+        from repro.models.layers import sable_patterns
+
+        mine, theirs = patterns(cfg), sable_patterns(mcfg)
+        for name in ("in", "out"):
+            p = theirs[name]
+            if not (np.array_equal(p.rows, mine[name][0])
+                    and np.array_equal(p.cols, mine[name][1])):
+                raise ValueError(f"the program's {name} pattern is not the configuration's")
+    return mcfg
+
+
+def work(cfg: dict):
+    """``(per_token, per_key, per_logits_row)`` operations that ``mfu.serve``
+    counts (``workcount.decoder_flops``): the FFN's stored tiles with
+    ``sable``, its three full matrices without."""
+    if "sable" in cfg:
+        pats, sb = patterns(cfg), cfg["sable"]
+        tile = int(sb["block_m"]) * int(sb["block_n"])
+        ffn = (2 * len(pats["in"][0]) + len(pats["out"][0])) * tile  # w1, w3; w2
+    else:
+        ffn = 3 * int(cfg["hidden_size"]) * int(cfg["intermediate_size"])
+    return workcount.decoder_flops(cfg, ffn)
+
+
+# ---------------------------------------------------------------------- #
 # weights
 # ---------------------------------------------------------------------- #
 def _leaf_name(path) -> str:
@@ -75,7 +140,7 @@ def _leaf_law(name: str, cfg: dict):
     """(mean, std) of one leaf, by its place in the model."""
     d = int(cfg["hidden_size"])
     f = int(cfg["intermediate_size"])
-    dens = float(cfg["sable"]["density"])
+    dens = float(cfg["sable"]["density"]) if "sable" in cfg else 1.0
     hd = int(cfg["num_attention_heads"]) * int(cfg["head_dim"])
     last = name.rsplit("/", 1)[-1]
     laws = {
@@ -177,9 +242,12 @@ def _layer(x, p, pin, pout, cfg_items, control):
     x = x + _mm(o, f32(p["mixer"]["wo"]), control)
 
     h = _rmsnorm(x, f32(p["ln_ffn"]), eps)
-    w1 = _dense(p["ffn"]["w1"], *pin, d, f)
-    w3 = _dense(p["ffn"]["w3"], *pin, d, f)
-    w2 = _dense(p["ffn"]["w2"], *pout, f, d)
+    if pin is None:  # no sable: the full matrices
+        w1, w3, w2 = (f32(p["ffn"][k]) for k in ("w1", "w3", "w2"))
+    else:
+        w1 = _dense(p["ffn"]["w1"], *pin, d, f)
+        w3 = _dense(p["ffn"]["w3"], *pin, d, f)
+        w2 = _dense(p["ffn"]["w2"], *pout, f, d)
     g = jax.nn.silu(_mm(h, w1, control)) * _mm(h, w3, control)
     return x + _mm(g, w2, control)
 
@@ -209,9 +277,11 @@ def scores(params, cfg: dict, tokens, targets, control: bool = False):
     """Run the model over ``tokens`` (B, S) layer by layer; returns the
     best logit (B, S), the logits of the tokens in each row of ``targets``
     (T, B, S), and the argmax (B, S) at every position, as NumPy arrays."""
-    pats = patterns(cfg)
-    pin = tuple(jnp.asarray(a) for a in pats["in"])
-    pout = tuple(jnp.asarray(a) for a in pats["out"])
+    pin = pout = None
+    if "sable" in cfg:
+        pats = patterns(cfg)
+        pin = tuple(jnp.asarray(a) for a in pats["in"])
+        pout = tuple(jnp.asarray(a) for a in pats["out"])
     eps = float(cfg["rms_norm_eps"])
     items = _cfg_items(cfg)
     tokens = jnp.asarray(tokens, jnp.int32)

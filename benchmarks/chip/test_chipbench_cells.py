@@ -1,5 +1,6 @@
 """BENCHMARK.json, the cells' files, and the benchmark's copies of the
 program's generators, checked without a chip."""
+import dataclasses
 import json
 import os
 import re
@@ -104,14 +105,66 @@ def test_granite_patterns_and_config_are_the_programs():
     from repro.models.layers import sable_patterns
 
     cell = harness.load_cell("granite-8b-sable.batch", BENCH)
-    mcfg = cell.driver().model_config(cell.config)
+    ref = cell.reference()
+    mcfg = ref.program_config(cell.config)
     assert (mcfg.d_model, mcfg.n_layers, mcfg.d_ff, mcfg.vocab_size) == (4096, 36, 14336, 49152)
-    mine = cell.reference().patterns(cell.config)
+    mine = ref.patterns(cell.config)
     for name, p in sable_patterns(mcfg).items():
         assert np.array_equal(p.rows, mine[name][0]) and np.array_equal(p.cols, mine[name][1])
-    per_token, per_key, per_row = workcount.decoder_flops(
-        cell.config, len(mine["in"][0]), len(mine["out"][0]))
+    per_token, per_key, per_row = ref.work(cell.config)
     # two operations per weight of the layers: attention (41.9M) and a
     # quarter of the three FFN matrices (44.0M) in each of 36 layers
     assert per_token == 2 * 36 * (41_943_040 + 3 * 896 * 128 * 128)
     assert per_key == 4 * 36 * 32 * 128 and per_row == 2 * 4096 * 49152
+
+
+def _serving_cells() -> dict:
+    """One cell of every configuration that the serving driver runs."""
+    cells = {}
+    for w in BENCH["workloads"]:
+        cell = harness.load_cell(w["name"], BENCH)
+        if cell.traffic["driver"] == "closed_loop":
+            cells.setdefault(cell.config_name, cell)
+    return cells
+
+
+SERVING = _serving_cells()
+
+
+@pytest.mark.parametrize("config", sorted(SERVING))
+def test_serving_reference_keeps_the_contract(config):
+    cell = SERVING[config]
+    ref = cell.reference()
+    for fn in ("program_config", "make_params", "scores", "work"):
+        assert callable(getattr(ref, fn, None)), fn
+    mcfg = ref.program_config(cell.config)
+    cfg = cell.config
+    assert (mcfg.n_layers, mcfg.d_model, mcfg.vocab_size) == (
+        cfg["num_hidden_layers"], cfg["hidden_size"], cfg["vocab_size"])
+    counts = ref.work(cfg)
+    assert len(counts) == 3 and all(int(n) == n > 0 for n in counts)
+
+
+@pytest.mark.parametrize("change", [{"hidden_size": 2048}, {"num_key_value_heads": 32}],
+                         ids=["width", "kv_heads"])
+def test_program_config_raises_on_a_size_departure(change):
+    cell = harness.load_cell("granite-8b-sable.batch", BENCH)
+    with pytest.raises(ValueError):
+        cell.reference().program_config(dict(cell.config, **change))
+
+
+def test_program_config_raises_on_a_moved_tile_pattern(monkeypatch):
+    import repro.models.layers as layers
+
+    real = layers.sable_patterns
+
+    def moved(mcfg):
+        pats = real(mcfg)
+        p = pats["out"]
+        pats["out"] = dataclasses.replace(
+            p, cols=tuple((c + 1) % (p.d_out // p.tk) for c in p.cols))
+        return pats
+    monkeypatch.setattr(layers, "sable_patterns", moved)
+    cell = harness.load_cell("granite-8b-sable.batch", BENCH)
+    with pytest.raises(ValueError, match="out pattern"):
+        cell.reference().program_config(cell.config)

@@ -52,6 +52,16 @@ def tiny_serve() -> harness.Cell:
     return dataclasses.replace(cell, config=cfg, traffic=tr, limits={"max_logit_gap": 0.025})
 
 
+def tiny_dense() -> harness.Cell:
+    """``tiny_serve`` with no ``sable``: granite's reduced sizes with the full
+    FFN matrices, compared against the reference's dense branch."""
+    cell = tiny_serve()
+    cfg = {k: v for k, v in cell.config.items() if k != "sable"}
+    # between the two readings: sound runs at most 0.0067, the float8
+    # control at least 0.047, over 12 seeds (2**33 + 3 + 7 i, 2 s windows)
+    return dataclasses.replace(cell, config=cfg, limits={"max_logit_gap": 0.02})
+
+
 def drive(cell, seed=2**33 + 3, seconds=0.5, fault=None, control=False):
     ctx = harness.Context(cell=cell, seed=seed, seconds=seconds, trace=False,
                           tracer=tracecalc.Tracer("", False),
@@ -102,6 +112,64 @@ def test_serving_control_is_not_correct():
     out, line = drive(tiny_serve(), seconds=2.0, control=True)
     value, limit = out.checks["max_logit_gap"]
     assert not line["correct"] and value > limit >= out.counters["program_gap"]
+
+
+def test_dense_serving_cell_is_correct():
+    out, line = drive(tiny_dense(), seconds=2.0)
+    assert line["correct"], line["checks"]
+    assert all(v["value"] and v["value"] > 0 for v in line["metrics"].values())
+    # the three full FFN matrices are counted, not tiles
+    attn = 96 * 6 * 16 + 2 * 96 * 2 * 16 + 6 * 16 * 96
+    assert out.counters["flops"][0] == 2 * 3 * (attn + 3 * 96 * 256)
+
+
+def test_dense_serving_control_is_not_correct():
+    out, line = drive(tiny_dense(), seconds=2.0, control=True)
+    value, limit = out.checks["max_logit_gap"]
+    assert not line["correct"] and value > limit >= out.counters["program_gap"]
+
+
+def _record_scheduler(monkeypatch) -> list:
+    """Keyword arguments of every ``make_scheduler`` call, and its scheduler."""
+    from repro.serve.engine import ServeEngine
+
+    made = []
+    real = ServeEngine.make_scheduler
+
+    def spy(self, **kw):
+        sched = real(self, **kw)
+        made.append((kw, sched))
+        return sched
+    monkeypatch.setattr(ServeEngine, "make_scheduler", spy)
+    return made
+
+
+def test_traffic_scheduler_keys_reach_the_scheduler(monkeypatch):
+    made = _record_scheduler(monkeypatch)
+    cell = tiny_serve()
+    cell = dataclasses.replace(cell, traffic=dict(cell.traffic,
+                                                  scheduler={"prefix_sharing": True}))
+    _, line = drive(cell, seconds=2.0)
+    ((kw, sched),) = made
+    assert kw == {"page_size": 4, "max_batch": 2, "prefix_sharing": True}
+    assert sched.prefix_sharing and line["correct"], line["checks"]
+
+
+def test_traffic_without_scheduler_key_builds_it_as_before(monkeypatch):
+    made = _record_scheduler(monkeypatch)
+    cell = tiny_serve()
+    assert "scheduler" not in cell.traffic
+
+    class Built(Exception):
+        pass
+
+    def stop(sched):
+        raise Built
+    with pytest.raises(Built):
+        drive(cell, fault=stop)
+    ((kw, sched),) = made
+    assert kw == {"page_size": 4, "max_batch": 2}
+    assert not sched.prefix_sharing and not sched.chunked_prefill
 
 
 def test_requests_are_the_same_work_for_every_seed():

@@ -28,9 +28,11 @@ def roofline_seconds(work: dict, peaks: dict) -> tuple[float, str]:
     return (t_bytes, "bytes") if t_bytes >= t_flops else (t_flops, "flops")
 
 
-def decoder_flops(cfg: dict, n_in_tiles: int, n_out_tiles: int):
-    """Operations of a decoder-only transformer with GQA attention and a
-    SwiGLU FFN whose matrices keep only their stored tiles.
+def decoder_flops(cfg: dict, ffn_weights: int):
+    """Operations of a decoder-only transformer with GQA attention whose
+    FFN multiplies ``ffn_weights`` weights a token in each layer (the
+    configuration's reference counts them: stored tiles only, where the
+    matrices are block-sparse).
 
     Returns ``(per_token, per_key, per_logits_row)``: the operations of one
     token through every layer's projections and FFN, the attention
@@ -41,11 +43,8 @@ def decoder_flops(cfg: dict, n_in_tiles: int, n_out_tiles: int):
     kv = int(cfg["num_key_value_heads"])
     dh = int(cfg["head_dim"])
     layers = int(cfg["num_hidden_layers"])
-    sb = cfg["sable"]
-    tile = int(sb["block_m"]) * int(sb["block_n"])
     attn_proj = d * h * dh + 2 * d * kv * dh + h * dh * d
-    ffn = (2 * n_in_tiles + n_out_tiles) * tile  # w1, w3 over d->d_ff; w2 back
-    per_token = 2 * layers * (attn_proj + ffn)
+    per_token = 2 * layers * (attn_proj + int(ffn_weights))
     per_key = 4 * layers * h * dh
     per_logits_row = 2 * d * int(cfg["vocab_size"])
     return per_token, per_key, per_logits_row
