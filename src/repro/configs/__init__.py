@@ -19,6 +19,7 @@ ARCH_IDS = [
     "llama4-scout-17b-a16e",
     "chameleon-34b",
     "seamless-m4t-large-v2",
+    "deepseek-v2-lite",
 ]
 
 _MODULES = {
@@ -32,6 +33,7 @@ _MODULES = {
     "llama4-scout-17b-a16e": "llama4_scout_17b_a16e",
     "chameleon-34b": "chameleon_34b",
     "seamless-m4t-large-v2": "seamless_m4t_large_v2",
+    "deepseek-v2-lite": "deepseek_v2_lite",
 }
 
 

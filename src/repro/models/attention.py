@@ -19,7 +19,7 @@ import numpy as np
 
 from ..distributed.ctx import MODEL, fetch
 from .config import ModelConfig
-from .layers import dense_init, rmsnorm, rope
+from .layers import dense_init, rmsnorm, rope, yarn_mscale
 
 __all__ = ["gqa_init", "gqa_apply", "mla_init", "mla_apply", "cross_init", "cross_apply"]
 
@@ -129,8 +129,8 @@ def gqa_apply(
     if cfg.qk_norm:
         q = rmsnorm(q, p["qn"], cfg.norm_eps)
         k = rmsnorm(k, p["kn"], cfg.norm_eps)
-    q = rope(q, positions, cfg.rope_theta)
-    k = rope(k, positions, cfg.rope_theta)
+    q = rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
+    k = rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
 
     new_cache = None
     if cache is not None:
@@ -164,10 +164,16 @@ def mla_init(key, cfg: ModelConfig, dtype=jnp.float32) -> dict:
     d, H = cfg.d_model, cfg.n_heads
     qk = m.qk_nope_dim + m.qk_rope_dim
     ks = jax.random.split(key, 6)
+    if m.q_lora_rank is None:  # queries projected directly (DeepSeek-V2-Lite)
+        q = {"wq": dense_init(ks[0], (d, H * qk), dtype=dtype)}
+    else:
+        q = {
+            "wdq": dense_init(ks[0], (d, m.q_lora_rank), dtype=dtype),
+            "qln": jnp.ones((m.q_lora_rank,), dtype),
+            "wuq": dense_init(ks[1], (m.q_lora_rank, H * qk), dtype=dtype),
+        }
     return {
-        "wdq": dense_init(ks[0], (d, m.q_lora_rank), dtype=dtype),
-        "qln": jnp.ones((m.q_lora_rank,), dtype),
-        "wuq": dense_init(ks[1], (m.q_lora_rank, H * qk), dtype=dtype),
+        **q,
         "wdkv": dense_init(ks[2], (d, m.kv_lora_rank + m.qk_rope_dim), dtype=dtype),
         "kvln": jnp.ones((m.kv_lora_rank,), dtype),
         "wukv": dense_init(
@@ -196,16 +202,19 @@ def mla_apply(
     if absorb is None:
         absorb = cache is not None and S == 1
 
-    cq = rmsnorm(x @ fetch(p["wdq"].astype(x.dtype), None, None), p["qln"], cfg.norm_eps)
-    q = (cq @ fetch(p["wuq"].astype(x.dtype), None, MODEL)).reshape(B, S, H, dn + dr)
+    if m.q_lora_rank is None:
+        q = x @ fetch(p["wq"].astype(x.dtype), None, MODEL)
+    else:
+        cq = rmsnorm(x @ fetch(p["wdq"].astype(x.dtype), None, None), p["qln"], cfg.norm_eps)
+        q = cq @ fetch(p["wuq"].astype(x.dtype), None, MODEL)
+    q = q.reshape(B, S, H, dn + dr)
     q_nope, q_rope = q[..., :dn], q[..., dn:]
-    q_rope = rope(q_rope, positions, cfg.rope_theta)
+    q_rope = rope(q_rope, positions, cfg.rope_theta, cfg.rope_scaling)
 
     ckv_full = x @ fetch(p["wdkv"].astype(x.dtype), None, None)  # (B,S,kv_lora+dr)
     ckv = rmsnorm(ckv_full[..., : m.kv_lora_rank], p["kvln"], cfg.norm_eps)
-    k_rope = rope(ckv_full[..., None, m.kv_lora_rank :], positions, cfg.rope_theta)[
-        :, :, 0
-    ]  # (B,S,dr) shared across heads
+    k_rope = rope(ckv_full[..., None, m.kv_lora_rank :], positions, cfg.rope_theta,
+                  cfg.rope_scaling)[:, :, 0]  # (B,S,dr) shared across heads
 
     new_cache = None
     if cache is not None:
@@ -217,17 +226,38 @@ def mla_apply(
         )
         new_cache = {"ckv": cckv, "kr": ckr}
         ckv_all, kr_all = cckv.astype(x.dtype), ckr.astype(x.dtype)
-        sk = ckv_all.shape[1]
-        mask = causal_mask(S, sk, cache_pos)
+        mask = causal_mask(S, ckv_all.shape[1], cache_pos)
     else:
         ckv_all, kr_all = ckv, k_rope
-        sk = S
         mask = causal_mask(S, S, 0) if causal else None
 
     wukv = fetch(p["wukv"].astype(x.dtype), None, MODEL).reshape(m.kv_lora_rank, H, dn + dv)
-    wuk, wuv = wukv[..., :dn], wukv[..., dn:]  # (kv_lora, H, dn/dv)
-    scale = 1.0 / np.sqrt(dn + dr)
+    with jax.named_scope("mla.attend"):
+        out = _mla_attend(q_nope, q_rope, ckv_all, kr_all, wukv, mask, mla_scale(cfg),
+                          absorb)
+    return out @ fetch(p["wo"].astype(x.dtype), MODEL, None), new_cache
 
+
+def mla_scale(cfg: ModelConfig) -> float:
+    """Softmax scale ``(qk_nope + qk_rope)^-1/2``, times YaRN's
+    ``mscale(factor, mscale_all_dim)^2`` where the rope is scaled."""
+    m = cfg.mla
+    scale = 1.0 / np.sqrt(m.qk_nope_dim + m.qk_rope_dim)
+    y = cfg.rope_scaling
+    if y is not None and y.mscale_all_dim:
+        scale *= yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+    return scale
+
+
+def _mla_attend(q_nope, q_rope, ckv_all, kr_all, wukv, mask, scale, absorb):
+    """The score and value contractions over the latent cache (the
+    ``mla.attend`` scope), absorbed (decode) or with K and V materialised
+    (train, prefill)."""
+    B, S, H, dn = q_nope.shape
+    sk, dr = ckv_all.shape[1], q_rope.shape[-1]
+    dv = wukv.shape[-1] - dn
+    wuk, wuv = wukv[..., :dn], wukv[..., dn:]  # (kv_lora, H, dn/dv)
+    dt = q_nope.dtype
     if absorb:
         # score = (q_nope @ wuk^T) . ckv + q_rope . k_rope  — MQA-like in
         # compressed space; per-step cost O(S*(kv_lora+dr)) per head.
@@ -237,22 +267,20 @@ def mla_apply(
         scores = (s1 + s2).astype(jnp.float32) * scale
         if mask is not None:
             scores = jnp.where(mask[:, 0], scores, NEG_INF)
-        probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
+        probs = jax.nn.softmax(scores, axis=-1).astype(dt)
         o_c = jnp.einsum("bhqs,bsc->bqhc", probs, ckv_all)  # compressed values
-        out = jnp.einsum("bqhc,chd->bqhd", o_c, wuv).reshape(B, S, H * dv)
-    else:
-        kv = jnp.einsum("bsc,chd->bshd", ckv_all, wukv)  # materialized k_nope|v
-        k_nope, v = kv[..., :dn], kv[..., dn:]
-        k = jnp.concatenate(
-            [k_nope, jnp.broadcast_to(kr_all[:, :, None], (B, sk, H, dr))], axis=-1
-        )
-        qf = jnp.concatenate([q_nope, q_rope], axis=-1)
-        scores = jnp.einsum("bqhd,bshd->bhqs", qf, k).astype(jnp.float32) * scale
-        if mask is not None:
-            scores = jnp.where(mask[:, 0], scores, NEG_INF)
-        probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-        out = jnp.einsum("bhqs,bshd->bqhd", probs, v).reshape(B, S, H * dv)
-    return out @ fetch(p["wo"].astype(x.dtype), MODEL, None), new_cache
+        return jnp.einsum("bqhc,chd->bqhd", o_c, wuv).reshape(B, S, H * dv)
+    kv = jnp.einsum("bsc,chd->bshd", ckv_all, wukv)  # materialized k_nope|v
+    k_nope, v = kv[..., :dn], kv[..., dn:]
+    k = jnp.concatenate(
+        [k_nope, jnp.broadcast_to(kr_all[:, :, None], (B, sk, H, dr))], axis=-1
+    )
+    qf = jnp.concatenate([q_nope, q_rope], axis=-1)
+    scores = jnp.einsum("bqhd,bshd->bhqs", qf, k).astype(jnp.float32) * scale
+    if mask is not None:
+        scores = jnp.where(mask[:, 0], scores, NEG_INF)
+    probs = jax.nn.softmax(scores, axis=-1).astype(dt)
+    return jnp.einsum("bhqs,bshd->bqhd", probs, v).reshape(B, S, H * dv)
 
 
 # ---------------------------------------------------------------------- #

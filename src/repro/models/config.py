@@ -33,15 +33,44 @@ class MoEConfig:
     # (kernels.bsr_ops sdd/dsd) — FLOPs track actual tokens, not E*C
     dropless: bool = False
     dropless_block: int = 8  # capacity-slot block rows per sparse block
+    # expert parallelism: ``num_experts`` is the router's (published) width;
+    # ``held = (first, count)`` is the slice of experts whose weights this
+    # layer holds.  With a slice the layer computes, dropless, only its own
+    # experts' part of the result for every token routed to them (the
+    # absent experts' part is left out); None holds all E through the
+    # capacity buckets above.
+    held: Optional[tuple] = None
+    norm_topk_prob: bool = True  # renormalise the top-k gates to sum to 1
+    router_f32: bool = False  # router logits and softmax in float32
+
+    @property
+    def held_first(self) -> int:
+        return self.held[0] if self.held else 0
+
+    @property
+    def n_held(self) -> int:
+        return self.held[1] if self.held else self.num_experts
 
 
 @dataclasses.dataclass(frozen=True)
 class MLAConfig:
-    q_lora_rank: int = 1536
+    q_lora_rank: Optional[int] = 1536  # None: queries projected directly (wq)
     kv_lora_rank: int = 512
     qk_nope_dim: int = 128
     qk_rope_dim: int = 64
     v_head_dim: int = 128
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnRope:
+    """YaRN rope scaling (DeepSeek-V2's ``rope_scaling`` with type yarn)."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -105,6 +134,7 @@ class ModelConfig:
     sable: Optional[SableConfig] = None
     qk_norm: bool = False
     rope_theta: float = 500000.0
+    rope_scaling: Optional[YarnRope] = None
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
     max_seq_len: int = 131072
@@ -172,8 +202,9 @@ def _layer_params(cfg: ModelConfig, spec: LayerSpec, active: bool) -> int:
         n += cfg.n_heads * cfg.head_dim * d  # wo
     elif spec.mixer == "mla":
         m = cfg.mla
-        n += d * m.q_lora_rank + m.q_lora_rank * cfg.n_heads * (
-            m.qk_nope_dim + m.qk_rope_dim
+        qk = cfg.n_heads * (m.qk_nope_dim + m.qk_rope_dim)
+        n += d * qk if m.q_lora_rank is None else (
+            d * m.q_lora_rank + m.q_lora_rank * qk
         )
         n += d * (m.kv_lora_rank + m.qk_rope_dim)
         n += m.kv_lora_rank * cfg.n_heads * (m.qk_nope_dim + m.v_head_dim)
@@ -196,7 +227,7 @@ def _layer_params(cfg: ModelConfig, spec: LayerSpec, active: bool) -> int:
         mc = cfg.moe
         mult = 3 if cfg.ffn_type == "swiglu" else 2
         per_expert = mult * d * mc.d_ff
-        routed = mc.top_k if active else mc.num_experts
+        routed = mc.top_k if active else mc.n_held
         n += routed * per_expert
         n += mc.num_shared * mult * d * (mc.shared_d_ff or mc.d_ff)
         n += d * mc.num_experts  # router

@@ -1,17 +1,22 @@
 """Shared layer primitives: norms, RoPE, FFNs (dense + SABLE-sparse)."""
 from __future__ import annotations
 
+import math
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ..distributed.ctx import MODEL, fetch
 from ..sparse.linear import random_pattern, sparse_matmul_auto
-from .config import ModelConfig
+from .config import ModelConfig, YarnRope
 
 __all__ = [
     "rmsnorm",
     "rope",
+    "yarn_inv_freq",
+    "yarn_mscale",
     "ffn_apply",
     "ffn_init",
     "dense_init",
@@ -26,16 +31,52 @@ def rmsnorm(x: jnp.ndarray, w: jnp.ndarray, eps: float = 1e-5) -> jnp.ndarray:
     return (x * w.astype(jnp.float32)).astype(dt)
 
 
-def rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
-    """Rotary embeddings.  x: (B, S, H, D), positions: (B, S) or (S,)."""
+def yarn_mscale(scale: float, mscale: float) -> float:
+    """YaRN's magnitude factor ``0.1 * mscale * ln(scale) + 1`` (1 for
+    ``scale <= 1``)."""
+    return 1.0 if scale <= 1 else 0.1 * mscale * math.log(scale) + 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float, yarn: YarnRope) -> tuple:
+    """(inverse frequencies (dim/2,) float32, low, high) of YaRN rope, as
+    DeepSeek-V2 computes them: frequencies above the correction range
+    ``[low, high]`` are kept, those below are divided by ``yarn.factor``,
+    and a linear ramp blends the two inside it."""
+    def corr(rotations):
+        return dim * math.log(yarn.original_max_position / (rotations * 2 * math.pi)) / (
+            2 * math.log(theta))
+
+    low = max(math.floor(corr(yarn.beta_fast)), 0)
+    high = min(math.ceil(corr(yarn.beta_slow)), dim - 1)
+    i = np.arange(dim // 2, dtype=np.float64)
+    ramp = np.clip((i - low) / ((high - low) or 0.001), 0.0, 1.0)
+    base = 1.0 / theta ** (2 * i / dim)
+    inv = base * (ramp / yarn.factor + (1.0 - ramp))
+    return inv.astype(np.float32), low, high
+
+
+def rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
+         scaling: Optional[YarnRope] = None) -> jnp.ndarray:
+    """Rotary embeddings on the half-split layout.  x: (B, S, H, D),
+    positions: (B, S) or (S,).  ``scaling`` takes the
+    frequencies and the cos/sin magnitude from YaRN; the tables are
+    computed once, at trace time."""
     d = x.shape[-1]
     half = d // 2
-    freqs = 1.0 / (theta ** (np.arange(0, half, dtype=np.float32) / half))
+    if scaling is None:
+        freqs = 1.0 / (theta ** (np.arange(0, half, dtype=np.float32) / half))
+    else:
+        freqs, _, _ = yarn_inv_freq(d, theta, scaling)
     if positions.ndim == 1:
         positions = positions[None, :]
     ang = positions[..., None].astype(jnp.float32) * freqs  # (B, S, half)
     cos = jnp.cos(ang)[:, :, None, :]
     sin = jnp.sin(ang)[:, :, None, :]
+    if scaling is not None:
+        m = yarn_mscale(scaling.factor, scaling.mscale) / yarn_mscale(
+            scaling.factor, scaling.mscale_all_dim)
+        if m != 1.0:
+            cos, sin = cos * m, sin * m
     x1, x2 = x[..., :half], x[..., half:]
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

@@ -9,6 +9,20 @@ HLO and is counted by the roofline.
 
 Shared experts (DeepSeek-style) are a dense FFN branch added to the routed
 output.  The router aux (load-balance) loss follows Switch/DeepSeek.
+
+Expert parallelism by slice (``MoEConfig.held = (first, count)``): the
+router keeps its E outputs and its top-k, but the layer holds only
+``count`` experts' weights, ``(count, d, f)``, and computes for every
+token its held experts' part of the result: each held expert's bucket has
+a slot for every token (capacity = the token count), so no (token, held
+expert) pair is ever dropped, at prefill or at decode.  What the absent
+experts would add is left out; on one chip the layer runs without the
+exchange that would bring it.
+
+Named scopes (trace-time only): ``moe.route`` (router, softmax, top-k,
+gates), ``moe.experts`` (dispatch to the experts, their FFN, the combine),
+``moe.shared`` (the shared experts).  ``moe_apply_counted`` also returns
+the (token, expert) pairs whose result reaches the output.
 """
 from __future__ import annotations
 
@@ -20,15 +34,16 @@ from ..distributed.ctx import DP, MODEL, constrain, fetch
 from .config import ModelConfig
 from .layers import _act, dense_init
 
-__all__ = ["moe_init", "moe_apply"]
+__all__ = ["moe_init", "moe_apply", "moe_apply_counted"]
 
 
 def moe_init(key, cfg: ModelConfig, dtype=jnp.float32) -> dict:
     mc = cfg.moe
-    d, E, f = cfg.d_model, mc.num_experts, mc.d_ff
+    d, f = cfg.d_model, mc.d_ff
+    E = mc.n_held
     ks = jax.random.split(key, 7)
     p = {
-        "router": dense_init(ks[0], (d, E), scale=0.02, dtype=dtype),
+        "router": dense_init(ks[0], (d, mc.num_experts), scale=0.02, dtype=dtype),
         "w1": dense_init(ks[1], (E, d, f), dtype=dtype),
         "w2": dense_init(ks[2], (E, f, d), dtype=dtype),
     }
@@ -102,8 +117,74 @@ def _dropless_ffn(p: dict, buf: jnp.ndarray, counts: jnp.ndarray,
     return out.reshape(G, E, C, d)
 
 
+def _route(p: dict, x: jnp.ndarray, cfg: ModelConfig):
+    """Router probabilities (..., E) in float32, and the top-k gates and
+    expert ids (..., k); the gates renormalised with ``norm_topk_prob``."""
+    mc = cfg.moe
+    if mc.router_f32:
+        logits = jnp.matmul(x.astype(jnp.float32),
+                            fetch(p["router"].astype(jnp.float32), None, None),
+                            precision=jax.lax.Precision.HIGHEST)
+    else:
+        logits = (x @ fetch(p["router"].astype(x.dtype), None, None)).astype(jnp.float32)
+    probs = jax.nn.softmax(logits, axis=-1)
+    gate, idx = jax.lax.top_k(probs, mc.top_k)
+    if mc.norm_topk_prob:
+        gate = gate / jnp.clip(gate.sum(-1, keepdims=True), 1e-9)
+    return probs, gate, idx
+
+
+def _aux_loss(probs, idx, cfg: ModelConfig):
+    """Switch-style load-balance loss over the router's E experts."""
+    E = cfg.moe.num_experts
+    me = probs.reshape(-1, E).mean(axis=0)  # mean router prob per expert
+    ce = jnp.bincount(idx.reshape(-1), length=E).astype(jnp.float32) / idx.size
+    return cfg.moe.aux_loss_coef * E * jnp.sum(me * ce)
+
+
+def _shared(p: dict, xt: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
+    """The shared experts: one dense FFN branch of width num_shared * d_ff."""
+    h = xt @ fetch(p["sw1"].astype(xt.dtype), None, MODEL)
+    if cfg.ffn_type == "swiglu":
+        h = jax.nn.silu(h) * (xt @ fetch(p["sw3"].astype(xt.dtype), None, MODEL))
+    else:
+        h = _act(cfg, h)
+    return h @ fetch(p["sw2"].astype(xt.dtype), MODEL, None)
+
+
+def _held_experts(p: dict, xt: jnp.ndarray, gate, idx, cfg: ModelConfig):
+    """The held experts' part of the result for tokens ``xt`` (T, d), and
+    the (token, held expert) pairs it holds.  Every held expert takes every
+    token (capacity = T), weighted by the token's gate for it, or 0 where
+    the router did not pick it: dropless at any batch."""
+    mc = cfg.moe
+    local = idx - mc.held_first  # (T, k) ids within the slice
+    here = (local >= 0) & (local < mc.n_held)
+    pick = jax.nn.one_hot(jnp.where(here, local, -1), mc.n_held, dtype=jnp.float32)
+    w = jnp.einsum("tk,tke->te", gate, pick)  # (T, held) combine weights
+    h = jnp.einsum("td,edf->etf", xt, fetch(p["w1"].astype(xt.dtype), MODEL, None, None))
+    if cfg.ffn_type == "swiglu":
+        g = jnp.einsum("td,edf->etf", xt, fetch(p["w3"].astype(xt.dtype), MODEL, None, None))
+        h = jax.nn.silu(h) * g
+    else:
+        h = _act(cfg, h)
+    out = jnp.einsum("etf,efd->etd", h, fetch(p["w2"].astype(xt.dtype), MODEL, None, None))
+    y = jnp.einsum("etd,te->td", out, w.astype(xt.dtype))
+    return y, here.sum().astype(jnp.int32)
+
+
 def moe_apply(p: dict, x: jnp.ndarray, cfg: ModelConfig):
-    """x: (B, S, d) -> (y, aux_loss).
+    """x: (B, S, d) -> (y, aux_loss); see ``moe_apply_counted``."""
+    y, aux, _ = moe_apply_counted(p, x, cfg)
+    return y, aux
+
+
+def moe_apply_counted(p: dict, x: jnp.ndarray, cfg: ModelConfig):
+    """x: (B, S, d) -> (y, aux_loss, pairs): ``pairs`` (int32) counts the
+    (token, expert) assignments whose expert output reaches ``y``.
+
+    With a held slice (``MoEConfig.held``), the layer's own experts' part
+    for every token (``_held_experts``); otherwise the capacity buckets:
 
     GShard-style grouped dispatch (§Perf iteration on deepseek-v2): tokens
     are bucketed per GROUP (= batch row, which is data-sharded), so the
@@ -119,6 +200,17 @@ def moe_apply(p: dict, x: jnp.ndarray, cfg: ModelConfig):
     mc = cfg.moe
     B, S, d = x.shape
     E, k = mc.num_experts, mc.top_k
+    if mc.held is not None:
+        xt = x.reshape(B * S, d)
+        with jax.named_scope("moe.route"):
+            probs, gate, idx = _route(p, xt, cfg)
+        with jax.named_scope("moe.experts"):
+            y, pairs = _held_experts(p, xt, gate, idx, cfg)
+        if mc.num_shared:
+            with jax.named_scope("moe.shared"):
+                y = y + _shared(p, xt, cfg)
+        return y.reshape(B, S, d), _aux_loss(probs, idx, cfg), pairs
+
     if S > 1:
         G, Tg = B, S  # groups = batch rows (data-sharded)
     else:
@@ -126,82 +218,70 @@ def moe_apply(p: dict, x: jnp.ndarray, cfg: ModelConfig):
     C = _capacity(Tg, cfg)
     xg = x.reshape(G, Tg, d)
 
-    logits = (
-        xg @ fetch(p["router"].astype(xg.dtype), None, None)
-    ).astype(jnp.float32)  # (G, Tg, E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate, idx = jax.lax.top_k(probs, k)  # (G, Tg, k)
-    gate = gate / jnp.clip(gate.sum(-1, keepdims=True), 1e-9)
+    with jax.named_scope("moe.route"):
+        probs, gate, idx = _route(p, xg, cfg)  # (G, Tg, E), (G, Tg, k)
 
-    # slot within the (group, expert) bucket via per-group masked cumsum
-    flat_idx = idx.reshape(G, Tg * k)
-    onehot = jax.nn.one_hot(flat_idx, E, dtype=jnp.int32)  # (G, Tg*k, E)
-    slot_flat = jnp.cumsum(onehot, axis=1) - onehot
-    slot = jnp.take_along_axis(slot_flat, flat_idx[..., None], axis=2)[..., 0]
-    slot = slot.reshape(G, Tg, k)
-    dropped = slot >= C
-    slot = jnp.where(dropped, C, slot)  # OOB => scatter mode='drop'
+    with jax.named_scope("moe.experts"):
+        # slot within the (group, expert) bucket via per-group masked cumsum
+        flat_idx = idx.reshape(G, Tg * k)
+        onehot = jax.nn.one_hot(flat_idx, E, dtype=jnp.int32)  # (G, Tg*k, E)
+        slot_flat = jnp.cumsum(onehot, axis=1) - onehot
+        slot = jnp.take_along_axis(slot_flat, flat_idx[..., None], axis=2)[..., 0]
+        slot = slot.reshape(G, Tg, k)
+        dropped = slot >= C
+        pairs = (~dropped).sum().astype(jnp.int32)
+        slot = jnp.where(dropped, C, slot)  # OOB => scatter mode='drop'
 
-    # dispatch: local scatter into (G, E, Cg, d).  vmap over G keeps the
-    # group dim a BATCH dim of the scatter, so the partitioner shards it
-    # over dp instead of replicating (explicit 3-D index arrays defeat
-    # batch-dim detection and cost ~80 TB/device — §Perf iteration log).
-    xk = jnp.broadcast_to(xg[:, :, None, :], (G, Tg, k, d))
-    buf = jax.vmap(
-        lambda i, s, v: jnp.zeros((E, C, d), xg.dtype).at[i, s].set(
-            v, mode="drop"
-        )
-    )(idx, slot, xk)
-    buf = constrain(buf, DP, MODEL, None, None)
+        # dispatch: local scatter into (G, E, Cg, d).  vmap over G keeps the
+        # group dim a BATCH dim of the scatter, so the partitioner shards it
+        # over dp instead of replicating (explicit 3-D index arrays defeat
+        # batch-dim detection and cost ~80 TB/device — §Perf iteration log).
+        xk = jnp.broadcast_to(xg[:, :, None, :], (G, Tg, k, d))
+        buf = jax.vmap(
+            lambda i, s, v: jnp.zeros((E, C, d), xg.dtype).at[i, s].set(
+                v, mode="drop"
+            )
+        )(idx, slot, xk)
+        buf = constrain(buf, DP, MODEL, None, None)
 
-    if mc.dropless:
-        # dropless: FFN only over occupied capacity blocks (block-sparse
-        # sdd/dsd over an in-trace topology; single flattened matrix, so
-        # no EP resharding — the dropless path is the per-batch-topology
-        # regime, not the EP-sharded dense-buffer one)
-        counts = onehot.sum(axis=1)  # (G, E) tokens routed per bucket
-        out_buf = _dropless_ffn(p, buf, counts, Tg, cfg)
-    else:
-        # expert FFN: batched einsum; E sharded over 'model' (EP) — the
-        # (G@dp, E, C, d) -> (G, E@model, C, d) reshard is the EP all-to-all
-        h = jnp.einsum("gecd,edf->gecf", buf,
-                       fetch(p["w1"].astype(xg.dtype), MODEL, None, None))
-        if cfg.ffn_type == "swiglu":
-            g = jnp.einsum("gecd,edf->gecf", buf,
-                           fetch(p["w3"].astype(xg.dtype), MODEL, None, None))
-            h = jax.nn.silu(h) * g
+        if mc.dropless:
+            # dropless: FFN only over occupied capacity blocks (block-sparse
+            # sdd/dsd over an in-trace topology; single flattened matrix, so
+            # no EP resharding — the dropless path is the per-batch-topology
+            # regime, not the EP-sharded dense-buffer one)
+            counts = onehot.sum(axis=1)  # (G, E) tokens routed per bucket
+            out_buf = _dropless_ffn(p, buf, counts, Tg, cfg)
         else:
-            h = _act(cfg, h)
-        out_buf = jnp.einsum("gecf,efd->gecd", h,
-                             fetch(p["w2"].astype(xg.dtype), MODEL, None, None))
-    # return expert outputs to the data shards BEFORE the combine gather:
-    # an explicit all-gather over 'model' of the dense buffer (~0.3 GB per
-    # group) so the gather below stays local — letting the partitioner
-    # handle an E-sharded gather costs ~5x more (replicate+AR of (T,k,d))
-    out_buf = constrain(out_buf, DP, None, None, None)
+            # expert FFN: batched einsum; E sharded over 'model' (EP) — the
+            # (G@dp, E, C, d) -> (G, E@model, C, d) reshard is the EP all-to-all
+            h = jnp.einsum("gecd,edf->gecf", buf,
+                           fetch(p["w1"].astype(xg.dtype), MODEL, None, None))
+            if cfg.ffn_type == "swiglu":
+                g = jnp.einsum("gecd,edf->gecf", buf,
+                               fetch(p["w3"].astype(xg.dtype), MODEL, None, None))
+                h = jax.nn.silu(h) * g
+            else:
+                h = _act(cfg, h)
+            out_buf = jnp.einsum("gecf,efd->gecd", h,
+                                 fetch(p["w2"].astype(xg.dtype), MODEL, None, None))
+        # return expert outputs to the data shards BEFORE the combine gather:
+        # an explicit all-gather over 'model' of the dense buffer (~0.3 GB per
+        # group) so the gather below stays local — letting the partitioner
+        # handle an E-sharded gather costs ~5x more (replicate+AR of (T,k,d))
+        out_buf = constrain(out_buf, DP, None, None, None)
 
-    # combine: local gather per group; dropped tokens contribute zero
-    gathered = jax.vmap(
-        lambda b, i, s: b.at[i, s].get(mode="fill", fill_value=0)
-    )(out_buf, idx, slot)  # (G, Tg, k, d)
-    gathered = constrain(gathered, DP, None, None, None)
-    y = (gathered * gate[..., None].astype(xg.dtype)).sum(axis=2)
+        # combine: local gather per group; dropped tokens contribute zero
+        gathered = jax.vmap(
+            lambda b, i, s: b.at[i, s].get(mode="fill", fill_value=0)
+        )(out_buf, idx, slot)  # (G, Tg, k, d)
+        gathered = constrain(gathered, DP, None, None, None)
+        y = (gathered * gate[..., None].astype(xg.dtype)).sum(axis=2)
     y = y.reshape(B * S, d)
     xt = x.reshape(B * S, d)
 
     # shared experts (dense branch)
     if mc.num_shared:
-        h = xt @ fetch(p["sw1"].astype(xt.dtype), None, MODEL)
-        if cfg.ffn_type == "swiglu":
-            h = jax.nn.silu(h) * (xt @ fetch(p["sw3"].astype(xt.dtype), None, MODEL))
-        else:
-            h = _act(cfg, h)
-        y = y + h @ fetch(p["sw2"].astype(xt.dtype), MODEL, None)
+        with jax.named_scope("moe.shared"):
+            y = y + _shared(p, xt, cfg)
 
-    # Switch-style load-balance aux loss
-    me = probs.reshape(-1, E).mean(axis=0)  # mean router prob per expert
-    ce = jnp.bincount(flat_idx.reshape(-1), length=E).astype(jnp.float32) / (
-        G * Tg * k
-    )
-    aux = mc.aux_loss_coef * E * jnp.sum(me * ce)
-    return y.reshape(B, S, d), aux
+    return y.reshape(B, S, d), _aux_loss(probs, idx, cfg), pairs

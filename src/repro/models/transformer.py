@@ -7,6 +7,8 @@ Public entry points (all pure functions of (params, cfg, inputs)):
   init_cache(cfg, batch, s_max, dtype)          -> cache pytree
   prefill(params, cfg, tokens, cache)           -> (logits, cache)
   decode_step(params, cfg, token, cache, pos)   -> (logits, cache)
+  (``counts=True`` on prefill, prefill_chunk and decode_step appends the
+  int32 count of (token, expert) pairs the MoE layers computed)
   encode(params, cfg, src_embeds)               -> enc_out  (enc-dec only)
 
 ``lax.scan`` over stacked per-group parameters keeps HLO size independent
@@ -32,7 +34,7 @@ from .attention import (
 )
 from .config import GroupSpec, LayerSpec, ModelConfig
 from .layers import dense_init, ffn_apply, ffn_init, rmsnorm
-from .moe import moe_apply, moe_init
+from .moe import moe_apply_counted, moe_init
 from .ssm import mamba_apply, mamba_decode, mamba_init
 
 __all__ = [
@@ -136,6 +138,7 @@ def _block_apply(
 ):
     new_cache = {}
     aux = jnp.zeros((), jnp.float32)
+    pairs = jnp.zeros((), jnp.int32)
     eps = cfg.norm_eps
     for i, spec in enumerate(specs):
         p = p_slice[f"sub{i}"]
@@ -178,13 +181,14 @@ def _block_apply(
         if spec.ffn == "dense":
             x = x + ffn_apply(p["ffn"], rmsnorm(x, p["ln_ffn"], eps), cfg)
         elif spec.ffn == "moe":
-            y, a = moe_apply(p["moe"], rmsnorm(x, p["ln_ffn"], eps), cfg)
+            y, a, n = moe_apply_counted(p["moe"], rmsnorm(x, p["ln_ffn"], eps), cfg)
             x = x + y
             aux = aux + a
+            pairs = pairs + n
         # keep the residual stream batch-sharded between sub-layers so the
         # SPMD partitioner never round-trips it through other layouts
         x = constrain(x, DP, None, None)
-    return x, new_cache, aux
+    return x, new_cache, aux, pairs
 
 
 def _run_groups(
@@ -199,25 +203,27 @@ def _run_groups(
     enc_out,
     mode: str,
 ):
-    """Scan each group's stacked params (and cache) over its repeat dim."""
+    """Scan each group's stacked params (and cache) over its repeat dim.
+    Returns (x, caches, aux loss, MoE pairs computed)."""
     new_caches = []
     aux_total = jnp.zeros((), jnp.float32)
+    pairs_total = jnp.zeros((), jnp.int32)
     for gi, g in enumerate(groups):
         specs = g.layers
         gp = group_params[gi]
         gc = caches[gi] if caches is not None else None
 
         def body(carry, xs):
-            x, aux = carry
+            x, aux, pairs = carry
             p_slice, c_slice = xs
             # pin the dynamic-sliced layer weights to their storage layout
             # before the TP-layout fetches (see ctx.anchor_params)
             p_slice = anchor_params(p_slice)
-            out, nc, a = _block_apply(
+            out, nc, a, n = _block_apply(
                 cfg, specs, p_slice, x, positions, c_slice, cache_pos,
                 causal, enc_out, mode,
             )
-            return (out, aux + a), nc
+            return (out, aux + a, pairs + n), nc
 
         if cfg.remat == "full":
             body = jax.checkpoint(body, prevent_cse=False)
@@ -228,11 +234,11 @@ def _run_groups(
                 prevent_cse=False,
             )
 
-        (x, aux_total), nc_stack = jax.lax.scan(
-            body, (x, aux_total), (gp, gc)
+        (x, aux_total, pairs_total), nc_stack = jax.lax.scan(
+            body, (x, aux_total, pairs_total), (gp, gc)
         )
         new_caches.append(nc_stack)
-    return x, new_caches, aux_total
+    return x, new_caches, aux_total, pairs_total
 
 
 # ---------------------------------------------------------------------- #
@@ -307,7 +313,7 @@ def encode(params, cfg: ModelConfig, src_embeds):
     if "frontend_proj" in params:
         x = x @ params["frontend_proj"].astype(x.dtype)
     positions = jnp.arange(x.shape[1])
-    x, _, _ = _run_groups(
+    x, _, _, _ = _run_groups(
         cfg, cfg.enc_groups, params["enc_groups"], x, positions,
         None, None, False, None, "train",
     )
@@ -323,25 +329,27 @@ def forward_train(params, cfg: ModelConfig, batch: dict):
         enc_out = encode(params, cfg, batch["src_embeds"])
     x = _embed(params, cfg, tokens)
     positions = jnp.arange(tokens.shape[1])
-    x, _, aux = _run_groups(
+    x, _, aux, _ = _run_groups(
         cfg, cfg.groups, params["groups"], x, positions, None, None,
         cfg.causal, enc_out, "train",
     )
     return _unembed(params, cfg, x), aux
 
 
-def prefill(params, cfg: ModelConfig, tokens, cache, enc_out=None):
+def prefill(params, cfg: ModelConfig, tokens, cache, enc_out=None, counts=False):
     """Process the prompt, filling the cache at positions [0, S)."""
     x = _embed(params, cfg, tokens)
     positions = jnp.arange(tokens.shape[1])
-    x, new_cache, _ = _run_groups(
+    x, new_cache, _, pairs = _run_groups(
         cfg, cfg.groups, params["groups"], x, positions, cache, 0,
         cfg.causal, enc_out, "prefill",
     )
-    return _unembed(params, cfg, x[:, -1:]), new_cache
+    out = (_unembed(params, cfg, x[:, -1:]), new_cache)
+    return out + (pairs,) if counts else out
 
 
-def prefill_chunk(params, cfg: ModelConfig, tokens, cache, start, enc_out=None):
+def prefill_chunk(params, cfg: ModelConfig, tokens, cache, start, enc_out=None,
+                  counts=False):
     """Process prompt positions [start, start+S), writing the cache at the
     same offsets and attending over every cached position <= each query
     (`cache_pos` drives both the write offset and the causal-mask offset in
@@ -353,19 +361,21 @@ def prefill_chunk(params, cfg: ModelConfig, tokens, cache, start, enc_out=None):
     fully-paged cache."""
     x = _embed(params, cfg, tokens)
     positions = start + jnp.arange(tokens.shape[1])
-    x, new_cache, _ = _run_groups(
+    x, new_cache, _, pairs = _run_groups(
         cfg, cfg.groups, params["groups"], x, positions, cache, start,
         cfg.causal, enc_out, "prefill",
     )
-    return _unembed(params, cfg, x[:, -1:]), new_cache
+    out = (_unembed(params, cfg, x[:, -1:]), new_cache)
+    return out + (pairs,) if counts else out
 
 
-def decode_step(params, cfg: ModelConfig, token, cache, pos, enc_out=None):
+def decode_step(params, cfg: ModelConfig, token, cache, pos, enc_out=None, counts=False):
     """One decode step.  token: (B, 1) int32, pos: scalar int32 position."""
     x = _embed(params, cfg, token)
     positions = jnp.full((token.shape[0], 1), pos, dtype=jnp.int32)
-    x, new_cache, _ = _run_groups(
+    x, new_cache, _, pairs = _run_groups(
         cfg, cfg.groups, params["groups"], x, positions, cache, pos,
         cfg.causal, enc_out, "decode",
     )
-    return _unembed(params, cfg, x), new_cache
+    out = (_unembed(params, cfg, x), new_cache)
+    return out + (pairs,) if counts else out

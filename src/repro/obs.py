@@ -15,7 +15,9 @@ arguments:
     serve.step          running (lanes decoding), admitted (requests admitted)
       serve.admit
         serve.prefill   rid, tokens: the jitted prefill of ``tokens`` prompt
-                        positions and the argmax that waits for it
+                        positions and the argmax that waits for it;
+                        moe_pairs (models with MoE layers): the (token,
+                        expert) pairs its MoE layers computed
         kv.write        rid, bytes_d2h: the prefill cache scattered into the
                         request's pages on the device; bytes_d2h counts the
                         state leaves read back (0 for a fully-paged cache)
@@ -24,7 +26,9 @@ arguments:
       kv.write           counts host leaves of the chunk's cache, none when
                          the cache is fully paged: it is gathered on the
                          device)
-      serve.decode      lanes
+      serve.decode      lanes; moe_pairs (models with MoE layers): the
+                        (token, expert) pairs the lanes' MoE layers
+                        computed this step, read back in serve.readback
         kv.gather       bytes: each lane's write resolved on the host
                         (growth, copy-on-write, eviction under page
                         pressure) and the int32 page table built; bytes is
@@ -43,7 +47,12 @@ arguments:
 Every span of one request carries its ``rid``.  The same byte counts go
 into the scheduler's ``stats`` (``kv_h2d_bytes``: step arguments and KV put
 on the device; ``kv_d2h_bytes``: KV read back; ``decode_steps_with_view``:
-decode steps dispatched), so they are seen without a profiler too.
+decode steps dispatched; ``moe_pairs_here``: the ``moe_pairs`` above,
+summed over the MoE layers of every prefill and decode step), so they are
+seen without a profiler too.  The ``moe_pairs`` count comes back from the
+jitted prefill and lane step beside their outputs and is read with them:
+a span takes no stats once it has closed, so the decode step's count sits
+on ``serve.decode``, the one open when the readback ends.
 ``kv_parked_bytes`` counts the KV bytes copied by eviction, resume and the
 release of a parked sequence's shared pages — the only KV that still
 crosses, and only under page pressure; it is part of ``kv_d2h_bytes``
@@ -52,8 +61,14 @@ crosses, and only under page pressure; it is part of ``kv_d2h_bytes``
 The staged sparse programs name their phases with ``jax.named_scope``
 instead (trace-time only, free at run time): ``sable.pack`` around the
 gathers that lay the runtime values out in blocks or tiles, and
-``sable.multiply`` around the products and the scatter-adds.  The scope
-reaches each device op through its ``op_name`` metadata.
+``sable.multiply`` around the products and the scatter-adds.  The model
+does the same inside the serving programs (``serve_prefill``,
+``serve_prefill_chunk``, ``serve_lane_step``): ``moe.route`` (router,
+softmax, top-k, gates), ``moe.experts`` (dispatch to the held experts,
+their FFN, the combine), ``moe.shared`` (the shared experts) and
+``mla.attend`` (latent attention's score and value contractions, absorbed
+or materialised).  A scope reaches each device op through its ``op_name``
+metadata.
 """
 from __future__ import annotations
 

@@ -112,14 +112,18 @@ def _make_lane_step(cfg: ModelConfig, kv: PagedKVCache):
     writes each lane's new KV slice into the page resolved for it.  Takes
     ``(params, tokens (B, 1), arenas, table (B, VP), states, positions (B,),
     keys, temperatures, write pages (B,), copy src, copy dst)``; returns
-    ``(next_token (B,), logits (B, V) f32, (arenas, states))`` with the
-    arenas donated and updated in place.  The program is named
-    ``serve_lane_step`` (``jit_serve_lane_step`` in a trace)."""
+    ``(next_token (B,), logits (B, V) f32, (arenas, states, counts))`` with
+    the arenas donated and updated in place; ``counts`` is
+    ``{"moe_pairs": (B,) int32}`` for a model with MoE layers (each lane's
+    (token, expert) pairs computed, over the layers) and empty otherwise.
+    The program is named ``serve_lane_step`` (``jit_serve_lane_step`` in a
+    trace)."""
     paged, treedef = list(kv.paged), kv.treedef
+    moe = cfg.moe is not None
 
     def one(params, tok, cache_b, pos, rng, temp):
         cache1 = jax.tree.map(lambda a: a[:, None], cache_b)  # re-add B=1
-        logits, nc = decode_step(params, cfg, tok[None], cache1, pos)
+        logits, nc, *pairs = decode_step(params, cfg, tok[None], cache1, pos, counts=moe)
         row = logits[:, 0].astype(jnp.float32)  # (1, V) — engine layout
         greedy = jnp.argmax(row, axis=-1)
         sampled = jax.random.categorical(
@@ -130,7 +134,7 @@ def _make_lane_step(cfg: ModelConfig, kv: PagedKVCache):
             jax.lax.dynamic_slice_in_dim(a, pos, 1, axis=2)[:, 0, 0] if m else a
             for a, m in zip(jax.tree_util.tree_leaves(nc), paged)
         ]
-        return nxt[0], row[0], sl
+        return nxt[0], row[0], sl, {"moe_pairs": pairs[0]} if moe else {}
 
     lanes = jax.vmap(one, in_axes=(None, 0, 1, 0, 0, 0))
 
@@ -142,11 +146,11 @@ def _make_lane_step(cfg: ModelConfig, kv: PagedKVCache):
         cache = jax.tree_util.tree_unflatten(
             treedef, [next(views) if m else next(st) for m in paged]
         )
-        nxt, logits, sl = lanes(params, toks, cache, pos, keys, temps)
+        nxt, logits, sl, counts = lanes(params, toks, cache, pos, keys, temps)
         arenas = write_slots(
             arenas, pages, pos, [x for x, m in zip(sl, paged) if m]
         )
-        return nxt, logits, (arenas, [x for x, m in zip(sl, paged) if not m])
+        return nxt, logits, (arenas, [x for x, m in zip(sl, paged) if not m], counts)
 
     return jax.jit(serve_lane_step, donate_argnums=2)
 
@@ -227,14 +231,18 @@ class ContinuousBatchingScheduler:
                 "whole prefix and cannot be shared or rebuilt per chunk"
             )
 
-        self._prefill = jax.jit(
-            lambda params, toks, cache: prefill(params, cfg, toks, cache)
-        )
-        self._prefill_chunk = jax.jit(
-            lambda params, toks, cache, start: _prefill_chunk_fn(
-                params, cfg, toks, cache, start
-            )
-        )
+        # with MoE layers the prefills also return the (token, expert)
+        # pairs they computed (``moe_pairs_here``)
+        moe = cfg.moe is not None
+
+        def serve_prefill(params, toks, cache):
+            return prefill(params, cfg, toks, cache, counts=moe)
+
+        def serve_prefill_chunk(params, toks, cache, start):
+            return _prefill_chunk_fn(params, cfg, toks, cache, start, counts=moe)
+
+        self._prefill = jax.jit(serve_prefill)
+        self._prefill_chunk = jax.jit(serve_prefill_chunk)
         # fixed dense width for chunk compute: one retrace per chunk length
         self._prefill_width = self.kv.view_pages * int(page_size)
         self._lane_step = _make_lane_step(cfg, self.kv)
@@ -262,6 +270,8 @@ class ContinuousBatchingScheduler:
             "kv_d2h_bytes": 0,
             "kv_parked_bytes": 0,
             "decode_steps_with_view": 0,
+            # (token, expert) pairs the MoE layers computed (see repro.obs)
+            "moe_pairs_here": 0,
         }
 
     # ------------------------------------------------------------------ #
@@ -492,13 +502,14 @@ class ContinuousBatchingScheduler:
         """Whole-prompt prefill at admission — the plain path (both features
         off); frozen by the golden transcript, so it stays byte-stable."""
         P = req.prompt_len
-        with obs.span("serve.prefill", rid=req.rid, tokens=P):
+        with obs.span("serve.prefill", rid=req.rid, tokens=P) as sp:
             cache = init_cache(self.cfg, 1, P)
-            logits, cache = self._prefill(
+            logits, cache, *pairs = self._prefill(
                 self.params, jnp.asarray(req.prompt[None]), cache
             )
             row = logits[:, -1].astype(jnp.float32)  # (1, V)
             first = int(jnp.argmax(row, axis=-1)[0])
+            self._count_pairs(pairs, sp)
         self.stats["kv_d2h_bytes"] += self.kv.write_prefill(req.rid, cache, P)
         self.stats["prefill_tokens"] += P
         req.prefilled = P
@@ -576,8 +587,8 @@ class ContinuousBatchingScheduler:
         with obs.span("kv.upload", bytes_h2d=h2d):
             dense = jax.block_until_ready(jax.device_put(dense))
         self.stats["kv_h2d_bytes"] += h2d
-        with obs.span("serve.prefill", rid=req.rid, tokens=end - start):
-            logits, dense = self._prefill_chunk(
+        with obs.span("serve.prefill", rid=req.rid, tokens=end - start) as sp:
+            logits, dense, *pairs = self._prefill_chunk(
                 self.params,
                 jnp.asarray(req.prompt[None, start:end]),
                 dense,
@@ -586,6 +597,7 @@ class ContinuousBatchingScheduler:
             if end == P:
                 row = logits[:, -1].astype(jnp.float32)  # (1, V)
                 first = int(jnp.argmax(row, axis=-1)[0])
+            self._count_pairs(pairs, sp)
         self.stats["kv_d2h_bytes"] += self.kv.write_span(req.rid, dense, start, end)
         req.prefilled = end
         self.stats["prefill_tokens"] += end - start
@@ -622,6 +634,14 @@ class ContinuousBatchingScheduler:
                 and len(req.tokens) >= req.max_new_tokens
             ):
                 self._finish(req, i, self.clock(), ev)
+
+    def _count_pairs(self, pairs, sp) -> None:
+        """Add a program's MoE pair count (``[]`` without MoE layers) to
+        ``moe_pairs_here`` and to the span ``sp``."""
+        if pairs:
+            n = int(np.sum(pairs[0]))
+            self.stats["moe_pairs_here"] += n
+            sp.set_metadata(moe_pairs=n)
 
     def _release_parked_shared_one(self) -> bool:
         """Terminal-pressure escape valve: demote the youngest parked
@@ -737,8 +757,8 @@ class ContinuousBatchingScheduler:
             for i in active
         }
         if active:
-            with obs.span("serve.decode", lanes=len(active)):
-                self._decode_once(active, ev)
+            with obs.span("serve.decode", lanes=len(active)) as sp:
+                self._count_pairs(self._decode_once(active, ev), sp)
         self.stats["steps"] += 1
         for k, v in self.kv.share_stats.items():
             self.stats[k] = v
@@ -775,12 +795,14 @@ class ContinuousBatchingScheduler:
                     break
         return {i: self.lanes[i] for i in active if self.lanes[i] is not None}
 
-    def _decode_once(self, active: List[int], ev: dict) -> None:
+    def _decode_once(self, active: List[int], ev: dict) -> list:
+        """One lane step over the ``active`` lanes; returns the MoE pair
+        counts of the lanes that decoded (``[]`` without MoE layers)."""
         B = self.max_batch
         with obs.span("kv.gather") as sp:
             lane_reqs = self._reserve_writes(active, ev)
             if not lane_reqs:
-                return  # the only lane parked itself
+                return []  # the only lane parked itself
             rids = [lane_reqs[i].rid if i in lane_reqs else None for i in range(B)]
             table = self.kv.gather(rids)
             pages = self.kv.step_pages(rids)
@@ -814,7 +836,7 @@ class ContinuousBatchingScheduler:
         self.stats["decode_steps_with_view"] += 1
         d_toks, d_table, d_pos, d_keys, d_temps, d_pages, d_src, d_dst = dev[:8]
         with obs.span("serve.lane_step"):
-            nxt, logits, (arenas, new_states) = jax.block_until_ready(
+            nxt, logits, (arenas, new_states, counts) = jax.block_until_ready(
                 self._lane_step(
                     self.params, d_toks, self.kv.arenas, d_table, dev[8:],
                     d_pos, d_keys, d_temps, d_pages, d_src, d_dst,
@@ -822,11 +844,13 @@ class ContinuousBatchingScheduler:
             )
         self.kv.arenas = arenas
         kv_bytes = sum(int(x.nbytes) for x in new_states)
-        d2h = int(nxt.nbytes) + int(logits.nbytes) + kv_bytes
+        d2h = int(nxt.nbytes) + int(logits.nbytes) + kv_bytes + sum(
+            int(x.nbytes) for x in counts.values())
         with obs.span("serve.readback", bytes_d2h=d2h, kv_bytes=kv_bytes):
             nxt = np.asarray(nxt)
             logits = np.asarray(logits)
             new_states = [np.asarray(x) for x in new_states]
+            counts = {k: np.asarray(x) for k, x in counts.items()}
         self.stats["kv_d2h_bytes"] += kv_bytes
         now = self.clock()
         with obs.span("kv.append", lanes=len(lane_reqs)):
@@ -838,6 +862,9 @@ class ContinuousBatchingScheduler:
                 self.stats["decode_tokens"] += 1
                 if len(req.tokens) >= req.max_new_tokens:
                     self._finish(req, i, now, ev)
+        if "moe_pairs" not in counts:
+            return []
+        return [counts["moe_pairs"][sorted(lane_reqs)]]
 
     # ------------------------------------------------------------------ #
     def pending(self) -> int:

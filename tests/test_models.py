@@ -59,6 +59,7 @@ FULL_SIZES = {
     "llama4-scout-17b-a16e": 107.8e9,
     "chameleon-34b": 34.3e9,
     "seamless-m4t-large-v2": 1.4e9,
+    "deepseek-v2-lite": 15.7e9,
 }
 
 
@@ -73,7 +74,7 @@ def test_full_config_param_count(arch):
 @pytest.mark.parametrize(
     "arch",
     ["llama3-8b", "deepseek-v2-236b", "mamba2-1.3b", "jamba-1.5-large-398b",
-     "seamless-m4t-large-v2"],
+     "seamless-m4t-large-v2", "deepseek-v2-lite"],
 )
 def test_decode_matches_forward(arch):
     """prefill+decode token-by-token == teacher-forced forward."""
